@@ -9,8 +9,11 @@ The orchestrator runs :class:`~pilottai_tpu.serve.Serve` with a
 a REAL subprocess hosting agents behind its own LLM engine
 (``--provider cpu|tpu`` boots the in-tree JAX engine inside every worker
 — the TPU-VM deployment story, where each host serves its agents from
-its local chips). Tasks fan out over the wire; results, heartbeats and
-load stats flow back.
+its local chips). A chip belongs to ONE process: on a single host
+``--provider tpu`` only works with ``--workers 1`` (a second worker
+process fails or hangs reaching a chip the first one holds); several
+``tpu`` workers need one host each. Tasks fan out over the wire; results,
+heartbeats and load stats flow back.
 
 ``--kill-one`` SIGKILLs a worker mid-run to demonstrate the BASELINE
 config #5 behavior: its in-flight tasks fail into Serve's retry path and
@@ -153,7 +156,11 @@ async def run(n_workers: int, provider: str, kill_one: bool) -> None:
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--workers", type=int, default=2)
-    ap.add_argument("--provider", default="mock", choices=["mock", "cpu", "tpu"])
+    ap.add_argument(
+        "--provider", default="mock", choices=["mock", "cpu", "tpu"],
+        help="engine inside every worker process. tpu: a chip belongs to "
+             "one process, so on one host use --workers 1",
+    )
     ap.add_argument("--kill-one", action="store_true")
     args = ap.parse_args()
     asyncio.run(run(args.workers, args.provider, args.kill_one))

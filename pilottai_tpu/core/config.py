@@ -272,8 +272,8 @@ class LLMConfig(BaseModel):
         return v
     # Decode dispatch pipeline depth: chunks in flight before the device
     # thread blocks on the reader. Each extra level hides one
-    # host↔device round trip behind compute — the lever when the chip
-    # sits behind a high-latency tunnel; early-exit chunks keep
+    # host↔device sync behind compute — the lever when the sync cost is
+    # large next to a chunk's device time; early-exit chunks keep
     # over-dispatched levels nearly free (a chunk whose slots are all
     # done retires without running a weight pass). Every level carries
     # its own dispatch-time D2H copy, so any depth ≥ 1 pipelines.
@@ -391,8 +391,9 @@ class LLMConfig(BaseModel):
     # composes with paged KV, speculation and prefix caching.
     engine_kv_quantize: Optional[str] = None
     # Persistent XLA compilation cache (utils/compile_cache.py): None =
-    # enabled at the default dir (PILOTTAI_COMPILE_CACHE env or
-    # ~/.cache/pilottai_tpu/xla); "off" disables; else the directory.
+    # enabled at <checkout>/.jax_cache; "off" disables; else the
+    # directory. JAX_COMPILATION_CACHE_DIR, where set, places the cache
+    # and overrides this field.
     # Warm restarts (FaultTolerance respawns, worker redeploys) reuse
     # compiled programs instead of paying minutes of recompilation.
     engine_compile_cache: Optional[str] = None

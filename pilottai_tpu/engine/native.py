@@ -10,6 +10,7 @@ from that thread. Zero external API calls.
 from __future__ import annotations
 
 import asyncio
+import gc
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -44,7 +45,7 @@ from pilottai_tpu.parallel.mesh import (
     create_mesh,
     initialize_distributed,
 )
-from pilottai_tpu.parallel.sharding import shard_params
+from pilottai_tpu.parallel.sharding import param_shardings
 from pilottai_tpu.reliability import DegradeLadder
 from pilottai_tpu.utils.logging import get_logger
 
@@ -59,17 +60,12 @@ class NativeEngine(LLMBackend):
         self._log = get_logger(f"engine.{self.name}")
         self.batcher: Optional[ContinuousBatcher] = None
         self.tokenizer = load_tokenizer(config.tokenizer_path)
+        # A named model keeps its registry shape (vocabulary and head
+        # included) with or without a checkpoint: byte-tokenizer ids are
+        # valid in any vocabulary, and the models meant for cheap
+        # checkpoint-free serving (``*-byte``, ``llama-tiny``,
+        # ``protocol-*``) declare their own small vocabularies.
         self.model_cfg = get_model_config(config.model_name)
-        # No checkpoint + byte tokenizer → shrink the vocab to the byte
-        # tokenizer's so randomly-initialized serving is cheap and coherent.
-        if (
-            config.checkpoint_path is None
-            and isinstance(self.tokenizer, ByteTokenizer)
-            and self.model_cfg.vocab_size != self.tokenizer.vocab_size
-        ):
-            self.model_cfg = self.model_cfg.replace(
-                vocab_size=self.tokenizer.vocab_size, tie_embeddings=True
-            )
         dtype = jnp.bfloat16 if config.dtype == "bfloat16" else jnp.float32
         self.model_cfg = self.model_cfg.replace(dtype=dtype)
         # Weight quantization mode: engine_quant wins; the legacy
@@ -116,9 +112,18 @@ class NativeEngine(LLMBackend):
         # Multi-host bring-up over DCN when JAX_COORDINATOR_ADDRESS et al
         # are set; a no-op for single-process serving.
         initialize_distributed()
-        devices = (
-            jax.local_devices(backend="cpu") if self.platform == "cpu" else jax.devices()
-        )
+        if self.platform == "cpu":
+            devices = jax.local_devices(backend="cpu")
+        else:
+            devices = jax.devices()
+            if devices[0].platform != "tpu":
+                # Serving from whatever JAX found would report CPU work
+                # under the name of the chip.
+                raise RuntimeError(
+                    f'provider="tpu" needs a TPU, but JAX found platform '
+                    f"{devices[0].platform!r} ({devices[0].device_kind}); "
+                    f'use provider="cpu" to serve from the host'
+                )
         mesh_cfg = (
             MeshConfig.from_dict(self.config.mesh_shape)
             if self.config.mesh_shape
@@ -159,8 +164,11 @@ class NativeEngine(LLMBackend):
             # Random init. Single chip + int8: quantize leaf-by-leaf at
             # generation time — a full bf16 8B tree alone would overflow a
             # 16 GB chip before quantize_params could shrink it. Multi-
-            # chip: init dense and shard first (per-chip shards fit), then
-            # the quantize pass below shrinks the sharded leaves.
+            # chip: init dense UNDER the target shardings (each chip
+            # generates only its own shards — an eager init would land
+            # the whole tree, 16 GB for 8B bf16, on the first chip
+            # before shard_params ever ran), then the quantize pass
+            # below shrinks the sharded leaves.
             # int4 always quantizes FROM the dense init (no eager int8
             # intermediate): the packed values must match across the
             # single-chip and sharded boot paths for the byte-identity
@@ -182,12 +190,13 @@ class NativeEngine(LLMBackend):
                 # would migrate them back to the default backend).
                 params = jax.device_put(params, devices[0])
             else:
-                params = init_params(
-                    self.model_cfg, jax.random.PRNGKey(self.config.seed)
-                )
-                params = shard_params(
-                    params, param_logical_axes(self.model_cfg), self.mesh
-                )
+                model_cfg = self.model_cfg  # the closure keeps only this
+                params = jax.jit(
+                    lambda key: init_params(model_cfg, key),
+                    out_shardings=param_shardings(
+                        param_logical_axes(model_cfg), self.mesh
+                    ),
+                )(jax.random.PRNGKey(self.config.seed))
         if self.quant_mode in ("int8", "int4"):
             from pilottai_tpu.models.quant import quantize_params
 
@@ -252,7 +261,7 @@ class NativeEngine(LLMBackend):
                 tuple(self.config.engine_chunk_buckets)
                 if self.config.engine_chunk_buckets else None
             ),
-            on_tpu=(self.platform != "cpu" and devices[0].platform == "tpu"),
+            on_tpu=self.platform != "cpu",
             mesh=self.mesh,
             paged=paged,
             page_size=self.config.engine_page_size,
@@ -400,6 +409,12 @@ class NativeEngine(LLMBackend):
             loop = asyncio.get_running_loop()
             await loop.run_in_executor(None, self.batcher.stop)
             self.batcher = None
+            # The batcher's threads, closures and futures form reference
+            # cycles: without a collection the stopped engine's weights
+            # and KV pool (10+ GB at 8B) stay on the device until the
+            # cyclic collector happens to run, and the next engine in
+            # this process fails to allocate (seen on the chip, PR 21).
+            gc.collect()
 
     # ------------------------------------------------------------------ #
 
@@ -728,7 +743,12 @@ class NativeEngine(LLMBackend):
         )
 
     def get_metrics(self) -> Dict[str, Any]:
-        out: Dict[str, Any] = {"backend": self.name, "model": self.model_cfg.name}
+        out: Dict[str, Any] = {
+            "backend": self.name, "model": self.model_cfg.name,
+            # The shape actually served (not the registry's promise).
+            "vocab_size": self.model_cfg.vocab_size,
+            "tie_embeddings": self.model_cfg.tie_embeddings,
+        }
         if self.batcher is not None:
             out.update(self.batcher.get_metrics())
         return out

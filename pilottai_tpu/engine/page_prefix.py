@@ -28,8 +28,8 @@ Matching is always a PROPER prefix (at least one tail token must remain
 to produce the first generated token's logits), enforced by capping the
 walk at ``(len(ids) - 1) // page_size`` blocks.
 
-Closes VERDICT.md round-3 next-step 1 (with the paged paths in
-``engine/decode.py``): speculation + prefix caching + paged KV compose.
+With the paged paths in ``engine/decode.py``, speculation + prefix
+caching + paged KV compose.
 No reference counterpart (the reference has no KV anything —
 ``pilott/engine/llm.py:59`` calls a remote API); the parity target is
 radix/block prefix caching in production paged-KV LLM servers.

@@ -86,6 +86,11 @@ def _embed(cfg: ModelConfig, params: Dict[str, Any], tokens: jax.Array) -> jax.A
     return x
 
 
+def _rows_at(x: jax.Array, positions: jax.Array) -> jax.Array:
+    """``x[b, positions[b]]`` for every row: [B, T, E] → [B, E]."""
+    return jnp.take_along_axis(x, positions[:, None, None], axis=1)[:, 0]
+
+
 def _unembed(cfg: ModelConfig, params: Dict[str, Any], x: jax.Array) -> jax.Array:
     head = params["lm_head"] if "lm_head" in params else params["embed"].T
     # No spec: the logits projection is the plain last-axis contraction,
@@ -134,17 +139,19 @@ def _full_seq_block(
             q, k, v, positions, valid, window,
             scale=qscale, softcap=cfg.attn_softcap, mesh=ring_mesh,
         )
-    # Pallas flash kernel (fwd + custom-VJP bwd). Single chip calls it
-    # directly; on a mesh it runs per-shard under shard_map (batch over
-    # data/fsdp, heads over model) when the shapes divide.
-    elif use_flash and len(jax.devices()) == 1:
+    # Pallas flash kernel (fwd + custom-VJP bwd). A caller on ONE device
+    # passes no flash_mesh and calls it directly — however many chips
+    # the host has (the process-wide device count says nothing about
+    # this computation); on a mesh it runs per-shard under shard_map
+    # (batch over data/fsdp, heads over model) when the shapes divide.
+    elif use_flash and flash_mesh is None:
         from pilottai_tpu.ops.pallas.flash_attention import flash_attention
 
         attn = flash_attention(
             q, k, v, positions, positions, valid, window,
             scale=qscale, softcap=cfg.attn_softcap,
         )
-    elif use_flash and flash_mesh is not None and flash_sharding_ok(
+    elif use_flash and flash_sharding_ok(
         flash_mesh, q.shape[0], cfg.n_heads, cfg.n_kv_heads
     ):
         from pilottai_tpu.ops.pallas.flash_attention import (
@@ -192,9 +199,15 @@ def forward_prefill(
                              # is a TPU) must pass False — flash_enabled()
                              # only sees the default backend
     flash_mesh: Any = None,  # static Mesh → shard_map'd flash on multi-chip
+    logit_positions: Optional[jax.Array] = None,  # [B] — unembed only
+                             # this position of each row (admission reads
+                             # one row; [B, T, V] fp32 at a 128K vocab is
+                             # 8 GB for 8 prompts of 2048)
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Full-prompt forward. Returns (logits [B, T, V] fp32, k, v) where
-    k/v are [L, B, T, K, H] ready to insert into a KVCache."""
+    """Full-prompt forward. Returns (logits fp32, k, v): logits are
+    [B, T, V], or [B, V] at ``logit_positions`` when given (the same
+    arithmetic on those rows); k/v are [L, B, T, K, H] ready to insert
+    into a KVCache."""
     x = _embed(cfg, params, tokens)
     x = with_logical_constraint(x, ("batch", "seq", None))
     sin, cos = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
@@ -224,6 +237,8 @@ def forward_prefill(
     x, (ks, vs) = jax.lax.scan(
         layer_fn, x, (params["layers"], windows)
     )
+    if logit_positions is not None:
+        x = _rows_at(x, logit_positions)                 # [B, E]
     x = rms_norm(x, params["final_norm"]["scale"], cfg.rms_eps, cfg.rms_offset)
     logits = _unembed(cfg, params, x)
     return logits, ks, vs

@@ -40,13 +40,12 @@ and the whole estimate is reconciled against the profiler-derived truth
 (tests/test_attribution.py) so drift cannot ship silently.
 
 Import cost: stdlib + utils only — no jax (``obs`` package constraint);
-``peak_flops_per_chip`` takes a platform string instead of sniffing
-devices.
+``peak_flops_per_chip`` takes the ``device_kind`` string its caller
+read from jax instead of sniffing devices.
 """
 
 from __future__ import annotations
 
-import os
 import threading
 import time
 from collections import deque
@@ -56,22 +55,31 @@ from pilottai_tpu.utils.metrics import MetricsRegistry, global_metrics
 
 PHASES = ("prefill", "decode", "sampling", "collective")
 
-# bf16 peak per chip. TPU v5e: 197 TFLOP/s (the constant bench.py has
-# always used); the CPU figure is a nominal placeholder so CPU runs
-# produce finite, comparable-within-themselves MFU values.
-_PEAK_FLOPS = {"tpu": 197e12, "gpu": 100e12, "cpu": 1e12}
+# Published per-chip peaks, keyed by ``jax.devices()[0].device_kind``.
+# A device that is not in the table is an error, not a default: an MFU
+# over a guessed peak is not a measurement. Add a part with its source.
+#   "TPU v5 lite" (v5e) — Google Cloud documentation, "TPU v5e":
+#       197 TFLOP/s bf16, 16 GB HBM at 819 GB/s.
+#   "cpu" — NOT a published peak: a nominal 1 TFLOP/s so host runs (the
+#       test suite) produce finite, comparable-within-themselves shares.
+#       Nothing computed from it is a device metric.
+DEVICE_PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bytes_per_s": 819e9},
+    "cpu": {"flops": 1e12, "hbm_bytes_per_s": 0.0},
+}
 
 
-def peak_flops_per_chip(platform: str) -> float:
-    """Per-chip peak FLOP/s for ``platform`` ("tpu"/"gpu"/"cpu").
-    ``PILOTTAI_PEAK_FLOPS`` overrides for other parts (v5p, v6e...)."""
-    env = os.environ.get("PILOTTAI_PEAK_FLOPS")
-    if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass
-    return _PEAK_FLOPS.get(platform, _PEAK_FLOPS["cpu"])
+def peak_flops_per_chip(device_kind: str) -> float:
+    """Per-chip peak bf16 FLOP/s for a jax ``device_kind``; an unknown
+    kind raises (callers with another part pass ``peak_flops=`` to
+    ``configure`` or add the part to ``DEVICE_PEAKS``)."""
+    try:
+        return DEVICE_PEAKS[device_kind]["flops"]
+    except KeyError:
+        raise KeyError(
+            f"no published peak for device kind {device_kind!r}; add it "
+            f"to obs/attribution.py:DEVICE_PEAKS with its source"
+        ) from None
 
 
 class DeviceTimeAttributor:
@@ -91,7 +99,7 @@ class DeviceTimeAttributor:
         self._lock = threading.Lock()
         self.window_s = window_s
         self._flops_per_token = 0.0
-        self._peak_flops = _PEAK_FLOPS["cpu"]
+        self._peak_flops = DEVICE_PEAKS["cpu"]["flops"]
         self._n_chips = 1
         self._mesh_axes: Tuple[str, ...] = ()
         # (t_end, phase, dur_s, flops, axis) events and (t, gap_s) idle
@@ -125,20 +133,21 @@ class DeviceTimeAttributor:
         self,
         *,
         flops_per_token: float,
-        platform: str = "cpu",
+        device_kind: str = "cpu",
         peak_flops: Optional[float] = None,
         n_chips: int = 1,
         mesh_axes: Tuple[str, ...] = (),
     ) -> None:
         """Engine boot hook: the model's FLOPs/token formula
-        (``ModelConfig.flops_per_token()``), the platform peak and the
-        mesh shape. Also declares the per-axis collective gauges so the
+        (``ModelConfig.flops_per_token()``), the device's published
+        peak (by ``device_kind``; unknown kinds raise) and the mesh
+        shape. Also declares the per-axis collective gauges so the
         full exposition surface exists before the first collective."""
         with self._lock:
             self._flops_per_token = float(flops_per_token)
             self._peak_flops = (
                 peak_flops if peak_flops is not None
-                else peak_flops_per_chip(platform)
+                else peak_flops_per_chip(device_kind)
             )
             self._n_chips = max(int(n_chips), 1)
             self._mesh_axes = tuple(mesh_axes)

@@ -22,8 +22,7 @@ Division of labor:
   the gather on TPU).
 
 Design follows the ragged/paged attention literature cited in PAPERS.md;
-closes VERDICT.md next-step 7 (the docstring-only "paged variant" of
-round 1). No reference counterpart (the reference has no KV anything —
+it replaces the docstring-only "paged variant" of round 1. No reference counterpart (the reference has no KV anything —
 it calls a remote API, ``pilott/engine/llm.py:59``).
 """
 

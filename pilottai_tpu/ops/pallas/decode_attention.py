@@ -21,8 +21,7 @@ Two modes:
   inside the chunk scan.
 
 No reference counterpart (the reference computes no attention at all,
-SURVEY.md §2.13); this is the serving engine's per-token hot op, the
-fix for VERDICT.md Weak #4.
+SURVEY.md §2.13); this is the serving engine's per-token hot op.
 """
 
 from __future__ import annotations

@@ -362,16 +362,44 @@ def paged_decode_attention(
     return acc.reshape(B, N, H), m.reshape(B, N), l.reshape(B, N)
 
 
+# What the strip's double-buffered input blocks may take of a v5e core's
+# 16 MiB of scoped VMEM; the rest stays for the kernel's own temporaries
+# (dequantized pages, scores). Checked against the chip's compiler at
+# Llama-3-8B shapes in tests/test_tpu_compile.py.
+STRIP_VMEM_BUDGET = 12 * 1024 * 1024
+_LANES = 128
+
+
 def strip_vmem_bytes(
     n_strip: int, page_size: int, n_kv_heads: int, head_dim: int,
     itemsize: int, quantized: bool,
 ) -> int:
-    """Estimated VMEM the strip's K/V blocks pin per pipeline stage —
-    the batcher's autotuner rejects candidates whose double-buffered
-    strip would crowd the ~16 MB VMEM budget."""
+    """VMEM the strip's K/V (and scale) blocks pin per pipeline stage.
+
+    Scale blocks ride as ``(K, 1, P, 1)`` float32 (see ``_attend_page``):
+    the trailing singleton pads to a full 128-lane tile in VMEM, so one
+    scale block costs as much as a float32 page of head_dim 128 — four
+    times the int8 page it scales. Counting it as ``K*P*4`` bytes let the
+    autotuner offer an int8 pool a strip of 8 pages of 128 that the
+    chip's compiler refuses (21 MB of VMEM)."""
     kv = 2 * n_kv_heads * page_size * head_dim * itemsize
-    sc = 2 * n_kv_heads * page_size * 4 if quantized else 0
+    sc = 2 * n_kv_heads * page_size * _LANES * 4 if quantized else 0
     return n_strip * (kv + sc)
+
+
+def max_safe_strip(
+    want: int, max_pages: int, page_size: int, n_kv_heads: int,
+    head_dim: int, itemsize: int, quantized: bool,
+) -> int:
+    """Largest strip <= ``want`` (halving) whose double-buffered blocks
+    stay within ``STRIP_VMEM_BUDGET`` — blowing VMEM fails at compile
+    time, mid-serving."""
+    strip = max(1, min(want, max_pages))
+    while strip > 1 and 2 * strip_vmem_bytes(
+        strip, page_size, n_kv_heads, head_dim, itemsize, quantized
+    ) > STRIP_VMEM_BUDGET:
+        strip //= 2
+    return strip
 
 
 # --------------------------------------------------------------------- #
@@ -440,8 +468,6 @@ def paged_decode_attention_sharded(
     the unsharded kernel (tests/test_multichip.py pins parity)."""
     from jax.sharding import PartitionSpec as P
 
-    from pilottai_tpu.parallel.mesh import compat_shard_map
-
     shape = dict(mesh.shape)
     present = [
         a for a in batch_axes
@@ -496,7 +522,7 @@ def paged_decode_attention_sharded(
             interpret=interpret,
         )
 
-    return compat_shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=tuple(in_specs),

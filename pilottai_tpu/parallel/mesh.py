@@ -48,45 +48,6 @@ class MeshConfig:
         return cls(**{k: int(v) for k, v in d.items() if k in AXIS_NAMES})
 
 
-def compat_shard_map(fn, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` across the jax versions the repo supports: the
-    top-level alias (and its ``check_vma`` kwarg) only exists on newer
-    jax; 0.4.x ships ``jax.experimental.shard_map`` with ``check_rep``.
-    One shim so every sharded entry point (pipeline, ring attention,
-    sharded flash) degrades identically instead of each call site
-    AttributeError-ing on whichever jax the host has."""
-    try:
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_vma,
-        )
-
-
-def compat_set_mesh(mesh: Mesh):
-    """Context manager that makes ``mesh`` the ambient mesh across jax
-    versions. Newer jax requires ``jax.set_mesh`` around jitted code
-    that uses explicit shardings; on 0.4.x that API does not exist AND
-    the legacy ``with mesh:`` physical-mesh context must NOT be
-    substituted — it flips pjit into its xmap-era semantics, which
-    breaks donation aliasing (measured: trainer steps fail with
-    mismatched aliased buffer sizes). On 0.4.x the NamedShardings
-    attached to args/outputs already carry the mesh, so the correct
-    compat is a no-op context."""
-    try:
-        return jax.set_mesh(mesh)
-    except AttributeError:
-        import contextlib
-
-        return contextlib.nullcontext(mesh)
-
-
 def create_mesh(
     config: Optional[MeshConfig] = None,
     devices: Optional[Sequence[jax.Device]] = None,
@@ -134,8 +95,7 @@ def initialize_distributed(
     # (unreachable coordinator, wrong world size) must be LOUD — swallowing
     # it would let each host proceed with a local-only mesh and silently
     # inconsistent sharding.
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    if is_init is not None and is_init():
+    if jax.distributed.is_initialized():
         return
     try:
         jax.distributed.initialize(

@@ -25,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from pilottai_tpu.parallel.mesh import compat_shard_map
-
 
 def pipeline_apply(
     block_fn: Callable[[Any, jax.Array], jax.Array],
@@ -85,7 +83,7 @@ def pipeline_apply(
         )
         return out
 
-    return compat_shard_map(
+    return jax.shard_map(
         per_stage,
         mesh=mesh,
         in_specs=(param_specs, x_spec),

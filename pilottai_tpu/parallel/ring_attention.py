@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from pilottai_tpu.ops.attention import NEG_INF, flash_enabled, flash_shapes_ok
-from pilottai_tpu.parallel.mesh import compat_shard_map
 from pilottai_tpu.parallel.sharding import _current_mesh
 
 # Logical shardings of the operands (mesh axes, not logical names, because
@@ -171,7 +170,7 @@ def ring_attention(
             .astype(v.dtype)
         )
 
-    return compat_shard_map(
+    return jax.shard_map(
         per_device_flash if use_flash else per_device,
         mesh=mesh,
         in_specs=(_Q_SPEC, _KV_SPEC, _KV_SPEC, _POS_SPEC, _VALID_SPEC, P()),

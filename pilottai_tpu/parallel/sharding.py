@@ -52,6 +52,33 @@ def named_sharding(
     return NamedSharding(mesh, logical_to_spec(logical_axes, rules))
 
 
+def _is_axes_leaf(x: Any) -> bool:
+    return x is None or (
+        isinstance(x, tuple)
+        and all(a is None or isinstance(a, str) for a in x)
+    )
+
+
+def param_shardings(
+    logical_tree: Any,
+    mesh: Mesh,
+    rules: Optional[Dict[str, Any]] = None,
+) -> Any:
+    """The ``NamedSharding`` pytree for a pytree of logical axis tuples
+    (``None`` leaf = replicated) — what ``shard_params`` places onto, and
+    what a jitted init takes as ``out_shardings`` so every chip generates
+    only its own shards."""
+    return jax.tree.map(
+        lambda axes: (
+            NamedSharding(mesh, P())
+            if axes is None
+            else named_sharding(mesh, axes, rules)
+        ),
+        logical_tree,
+        is_leaf=_is_axes_leaf,
+    )
+
+
 def shard_params(
     params: Any,
     logical_tree: Any,
@@ -60,24 +87,15 @@ def shard_params(
 ) -> Any:
     """Device-put a param pytree according to a parallel pytree of logical
     axis tuples (``None`` leaf = replicated)."""
-
-    def place(axes, leaf):
-        sharding = (
-            NamedSharding(mesh, P())
-            if axes is None
-            else named_sharding(mesh, axes, rules)
-        )
-        return jax.device_put(leaf, sharding)
-
     # Map over logical_tree FIRST so bare-None leaves ("replicated") are
     # honored — with params first, a None in the second tree would be
     # treated as an empty subtree and raise a structure mismatch.
     return jax.tree.map(
-        place,
+        lambda axes, sharding, leaf: jax.device_put(leaf, sharding),
         logical_tree,
+        param_shardings(logical_tree, mesh, rules),
         params,
-        is_leaf=lambda x: x is None
-        or (isinstance(x, tuple) and all(a is None or isinstance(a, str) for a in x)),
+        is_leaf=_is_axes_leaf,
     )
 
 

@@ -2,7 +2,7 @@
 
 A device-side exception reaches the batcher's except-arms and is handled
 (recovery, rebuild). A *stuck* dispatch — a hung XLA call, a wedged
-collective on a multichip mesh, a tunnel that silently stopped moving
+collective on a multichip mesh, a transfer that silently stopped moving
 bytes — never raises anywhere: the device thread blocks inside the
 dispatch, folds stop arriving, and every client simply hangs until its
 own timeout. The watchdog turns that silent state into an explicit one:

@@ -26,7 +26,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from pilottai_tpu.models.common import ModelConfig, init_params, param_logical_axes
 from pilottai_tpu.models.transformer import forward_train
-from pilottai_tpu.parallel.mesh import compat_set_mesh, create_mesh
+from pilottai_tpu.parallel.mesh import create_mesh
 from pilottai_tpu.parallel.sharding import (
     logical_to_spec,
     shard_params,
@@ -147,7 +147,7 @@ class Trainer:
         # different layouts, and the step's donated state then fails
         # aliasing at dispatch (jax 0.4.x rejects it; newer jax silently
         # copies — either way the donation is lost).
-        with compat_set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             return jax.jit(
                 _init,
                 out_shardings=(param_shardings, self._opt_shardings()),
@@ -263,7 +263,7 @@ class Trainer:
     ) -> Tuple[Tuple[Any, Any], Dict[str, jax.Array]]:
         params, opt_state = state
         tokens, valid, loss_start = self.shard_batch(batch)
-        with compat_set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             params, opt_state, metrics = self._step(
                 params, opt_state, tokens, valid, loss_start
             )

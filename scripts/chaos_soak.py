@@ -37,8 +37,10 @@ import time
 
 def _force_virtual_devices() -> None:
     """8 virtual CPU devices, set BEFORE jax's first import (device
-    topology is fixed then — same trick as tests/conftest.py)."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    topology is fixed then — same trick as tests/conftest.py). The soak
+    is a CPU run by construction: it never takes a chip (a chip belongs
+    to one process), and nothing it reports is a device number."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (

@@ -94,13 +94,20 @@ def test_snapshot_phase_shares_and_reset_window():
     assert attr.snapshot()["attributed_s"] == 0.0
 
 
-def test_peak_flops_platform_table_and_env_override(monkeypatch):
-    assert peak_flops_per_chip("tpu") == pytest.approx(197e12)
-    assert peak_flops_per_chip("unknown") == peak_flops_per_chip("cpu")
+def test_peak_flops_keyed_by_device_kind_unknown_raises(monkeypatch):
+    """Peaks come from the published table by ``device_kind``; a device
+    that is not in it is an error, never the CPU placeholder, and no
+    environment variable overrides the table."""
+    assert peak_flops_per_chip("TPU v5 lite") == pytest.approx(197e12)
+    with pytest.raises(KeyError, match="TPU v9"):
+        peak_flops_per_chip("TPU v9")
+    with pytest.raises(KeyError):
+        peak_flops_per_chip("tpu")  # a platform is not a device kind
     monkeypatch.setenv("PILOTTAI_PEAK_FLOPS", "4.5e14")
-    assert peak_flops_per_chip("tpu") == pytest.approx(4.5e14)
-    monkeypatch.setenv("PILOTTAI_PEAK_FLOPS", "not-a-float")
-    assert peak_flops_per_chip("tpu") == pytest.approx(197e12)
+    assert peak_flops_per_chip("TPU v5 lite") == pytest.approx(197e12)
+    attr, _ = _attr()
+    with pytest.raises(KeyError):
+        attr.configure(flops_per_token=1e9, device_kind="TPU v9")
 
 
 def test_flops_per_token_dense_and_moe():

@@ -220,7 +220,7 @@ def test_validate_knobs_flags_out_of_bounds_and_unknown():
 def _cache_dir(tmp_path, monkeypatch):
     from pilottai_tpu.utils import compile_cache
 
-    monkeypatch.setenv("PILOTTAI_COMPILE_CACHE", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     monkeypatch.setattr(compile_cache, "_enabled_dir", None)
     return tmp_path
 

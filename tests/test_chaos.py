@@ -507,27 +507,35 @@ def test_recovery_replays_folded_tokens_and_streams_without_duplicates():
     stream resumes at the next NEW token (no duplicates — the collected
     stream equals the final result), and greedy output matches the
     uninjected run."""
-    b = _tiny_batcher(n_slots=1)
+    # 192 tokens = a dozen chunks: the device thread runs at most
+    # PIPELINE_DEPTH + 2 dispatches ahead of the reader, so most of them
+    # are still to come when the first fold arms the fault (at 64 tokens
+    # every chunk could already be in flight).
+    b = _tiny_batcher(n_slots=1, max_seq=256)
     b.start()
     try:
         ref = b.submit(
-            GenRequest(prompt_ids=[3, 4, 5], max_new_tokens=64)
+            GenRequest(prompt_ids=[3, 4, 5], max_new_tokens=192)
         ).result(timeout=120)
         before = global_metrics.get("engine.tokens_replayed")
         got: list = []
+
+        def on_tokens(ids):
+            # Break the device from INSIDE the first fold: real tokens
+            # have streamed, and most of the budget is still to dispatch
+            # — arming from the test thread after polling raced a fast
+            # decode that could finish first.
+            if not got:
+                global_injector.arm(
+                    "engine.step",
+                    RuntimeError("mid-decode device failure"), times=1,
+                )
+            got.extend(ids)
+
         req = GenRequest(
-            prompt_ids=[3, 4, 5], max_new_tokens=64,
-            on_tokens=lambda ids: got.extend(ids),
+            prompt_ids=[3, 4, 5], max_new_tokens=192, on_tokens=on_tokens,
         )
         fut = b.submit(req)
-        # Wait until real tokens have folded, THEN break the device.
-        t_end = time.time() + 60
-        while time.time() < t_end and not got:
-            time.sleep(0.005)
-        assert got, "no tokens streamed before arming the fault"
-        global_injector.arm(
-            "engine.step", RuntimeError("mid-decode device failure"), times=1
-        )
         out = fut.result(timeout=120)
         assert out == ref
         assert got == out  # stream == result: nothing duplicated or lost

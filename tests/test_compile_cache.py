@@ -1,9 +1,11 @@
 """Persistent compilation cache: warm restarts reuse compiled programs.
 
-VERDICT r3 weak #4: every process start recompiled the whole engine
-(141.7 s on the chip), so FaultTolerance's respawn story cost minutes of
-dead time. The restart path must now provably hit the on-disk cache —
-asserted via the hit counter, not wall-clock (CI machines are noisy).
+Every process start used to recompile the whole engine (minutes at 8B),
+so FaultTolerance's respawn story cost minutes of dead time. The restart
+path must provably hit the on-disk cache — asserted via the hit counter,
+not wall-clock (CI machines are noisy) — and the cache must be placeable
+from outside: ``JAX_COMPILATION_CACHE_DIR`` where set, else the fixed
+``<checkout>/.jax_cache``.
 """
 
 import json
@@ -51,6 +53,8 @@ def _boot(cache_dir: str) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.dirname(os.path.dirname(__file__))
     env.pop("XLA_FLAGS", None)  # single-device process, like a respawn
+    # The explicit directory is under test: no placement from outside.
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     out = subprocess.run(
         [sys.executable, "-c", _BOOT, cache_dir],
         capture_output=True, text=True, timeout=600, env=env,
@@ -124,11 +128,78 @@ def test_adaptive_chunk_buckets_bound_decode_executables():
     )
 
 
-def test_enable_is_idempotent_and_off_disables(tmp_path):
+@pytest.fixture()
+def _cache_state(monkeypatch):
+    """``(compile_cache module, {jax config name: value set in code})``
+    for a resolution-order case, with both variables unset. Leaves the
+    process as it was: it runs the rest of the suite, and must not keep
+    the cache pointed at a tmp dir pytest is about to delete."""
     import jax
 
     import pilottai_tpu.utils.compile_cache as cc
 
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv("PILOTTAI_COMPILE_CACHE", raising=False)
+    monkeypatch.setattr(cc, "_enabled_dir", None)
+    prev_dir = jax.config.jax_compilation_cache_dir
+    updates = {}
+    real_update = jax.config.update
+
+    def recording_update(name, val):
+        updates[name] = val
+        real_update(name, val)
+
+    monkeypatch.setattr(jax.config, "update", recording_update)
+    yield cc, updates
+    real_update("jax_compilation_cache_dir", prev_dir)
+
+
+def test_placed_from_outside_uses_that_dir_and_sets_none_in_code(
+    _cache_state, tmp_path, monkeypatch
+):
+    """``JAX_COMPILATION_CACHE_DIR`` set: that directory wins over the
+    argument (the ``engine_compile_cache`` field), the autotune and
+    profile stores follow it, and no directory is set in code."""
+    cc, updates = _cache_state
+    placed = tmp_path / "placed"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(placed))
+    assert enable_compilation_cache(str(tmp_path / "field")) == str(placed)
+    assert enable_compilation_cache() == str(placed)
+    assert "jax_compilation_cache_dir" not in updates
+    assert placed.is_dir() and not (tmp_path / "field").exists()
+    assert cc.default_cache_dir() == str(placed)
+    cc.store_autotune("k", 4)
+    cc.store_profile("dep", {"a": 1})
+    assert (placed / "autotune.json").exists()
+    assert (placed / "profiles.json").exists()
+    assert cc.load_autotune("k") == 4
+
+
+def test_unset_defaults_to_checkout_jax_cache_and_old_variable_is_ignored(
+    _cache_state, tmp_path, monkeypatch
+):
+    """Unset: the fixed ``<checkout>/.jax_cache`` — never the home
+    directory, a temporary name, a pid or a time — and the retired
+    ``PILOTTAI_COMPILE_CACHE`` changes nothing."""
+    cc, updates = _cache_state
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(checkout, ".jax_cache")
+    assert cc.default_cache_dir() == want
+    monkeypatch.setenv("PILOTTAI_COMPILE_CACHE", str(tmp_path / "old"))
+    monkeypatch.setenv("HOME", str(tmp_path / "home"))
+    assert cc.default_cache_dir() == want
+    assert enable_compilation_cache() == want
+    assert updates["jax_compilation_cache_dir"] == want
+    assert not (tmp_path / "old").exists() and not (tmp_path / "home").exists()
+    assert str(cc._autotune_path()) == os.path.join(want, "autotune.json")
+
+
+def test_enable_is_idempotent_and_off_disables(tmp_path, monkeypatch):
+    import jax
+
+    import pilottai_tpu.utils.compile_cache as cc
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     prev_dir = jax.config.jax_compilation_cache_dir
     prev_enabled = cc._enabled_dir
     d = str(tmp_path / "cc")

@@ -52,9 +52,8 @@ def _admit(cfg, params, temps, budgets, eos=-1, seed0=10):
         jnp.full((A,), eos, jnp.int32),
         jnp.zeros((A,), bool),
     )
-    first, sampling = sample_prefill_tokens(
-        logits, jnp.asarray(lens), slots, sampling
-    )
+    last = logits[jnp.arange(A), jnp.maximum(jnp.asarray(lens) - 1, 0)]
+    first, sampling = sample_prefill_tokens(last, slots, sampling)
     dstate = admit_decode(
         dstate, slots, first, jnp.asarray(budgets, jnp.int32),
         jnp.asarray(lens > 0),

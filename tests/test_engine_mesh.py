@@ -165,7 +165,7 @@ def test_rebuild_requeues_later_groups():
                 k_.delete()
                 v_.delete()
             cache.lengths.delete()
-            raise RuntimeError("tunnel dropped mid-dispatch")
+            raise RuntimeError("device lost mid-dispatch")
         return real_admit(params_, cfg_, cache, dstate, sampling, *a, **k)
 
     bmod.admit_group = poison_once
@@ -177,7 +177,7 @@ def test_rebuild_requeues_later_groups():
         batcher.submit(req1)
         batcher.submit(req2)
         batcher.start()
-        with pytest.raises(RuntimeError, match="tunnel dropped"):
+        with pytest.raises(RuntimeError, match="device lost"):
             req1.future.result(timeout=60)
         # req2 was requeued and admitted against the REBUILT allocator:
         # it completes with real tokens (admission actually ran again).

@@ -9,7 +9,7 @@ from jax.sharding import Mesh
 from pilottai_tpu.models.common import init_params
 from pilottai_tpu.models.registry import get_model_config
 from pilottai_tpu.models.transformer import forward_prefill
-from pilottai_tpu.parallel.mesh import compat_set_mesh, MeshConfig, create_mesh
+from pilottai_tpu.parallel.mesh import MeshConfig, create_mesh
 from pilottai_tpu.parallel.pipeline import pipeline_apply, split_layers_to_stages
 from pilottai_tpu.train import Trainer, TrainConfig, synthetic_batches
 
@@ -102,7 +102,7 @@ def test_pipeline_matches_sequential(stage_mesh):
     x = jnp.asarray(rng.normal(size=(8, 4, 16)), jnp.float32)
     ref = jax.vmap(lambda xi: block_fn(params, xi))(x)
     staged = split_layers_to_stages(params, 4)
-    with compat_set_mesh(stage_mesh):
+    with jax.set_mesh(stage_mesh):
         got = jax.jit(
             lambda p, x: pipeline_apply(
                 block_fn, p, x, stage_mesh, batch_axes=("data",)
@@ -127,7 +127,7 @@ def test_pipeline_gradients_match(stage_mesh):
         )
 
     g_ref = jax.grad(loss_seq)(params)
-    with compat_set_mesh(stage_mesh):
+    with jax.set_mesh(stage_mesh):
         g_pp = jax.jit(jax.grad(loss_pp))(staged)
     for k in ("w", "b"):
         np.testing.assert_allclose(
@@ -142,7 +142,7 @@ def test_pipeline_fewer_microbatches_than_stages(stage_mesh):
     x = jnp.asarray(rng.normal(size=(2, 4, 16)), jnp.float32)
     ref = jax.vmap(lambda xi: block_fn(params, xi))(x)
     staged = split_layers_to_stages(params, 4)
-    with compat_set_mesh(stage_mesh):
+    with jax.set_mesh(stage_mesh):
         got = jax.jit(
             lambda p, x: pipeline_apply(
                 block_fn, p, x, stage_mesh, batch_axes=("data",)

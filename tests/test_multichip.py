@@ -63,7 +63,7 @@ def test_collective_gauge_arithmetic_synthetic():
     reg = MetricsRegistry()
     attr = DeviceTimeAttributor(registry=reg, window_s=60.0)
     attr.configure(
-        flops_per_token=1e9, platform="cpu", n_chips=8,
+        flops_per_token=1e9, device_kind="cpu", n_chips=8,
         mesh_axes=("data", "model"),
     )
     t0 = 1000.0

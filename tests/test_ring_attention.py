@@ -7,7 +7,7 @@ import pytest
 
 from pilottai_tpu.models.registry import get_model_config
 from pilottai_tpu.ops.attention import dot_product_attention, make_attention_mask
-from pilottai_tpu.parallel.mesh import compat_set_mesh, MeshConfig, create_mesh
+from pilottai_tpu.parallel.mesh import MeshConfig, create_mesh
 from pilottai_tpu.parallel.ring_attention import ring_attention
 from pilottai_tpu.train import Trainer, TrainConfig, synthetic_batches
 
@@ -40,7 +40,7 @@ def test_ring_matches_reference(mesh, window, softcap):
     ref = dot_product_attention(
         q, k, v, mask=mask, scale=H**-0.5, logit_softcap=softcap
     )
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         got = jax.jit(
             lambda *a: ring_attention(
                 *a, scale=H**-0.5, softcap=softcap, mesh=mesh
@@ -57,7 +57,7 @@ def test_ring_four_way(mesh_seq4):
     valid = jnp.full((4,), T, jnp.int32)
     mask = make_attention_mask(ps, T, valid)
     ref = dot_product_attention(q, k, v, mask=mask, scale=H**-0.5)
-    with compat_set_mesh(mesh_seq4):
+    with jax.set_mesh(mesh_seq4):
         got = jax.jit(
             lambda *a: ring_attention(*a, scale=H**-0.5, mesh=mesh_seq4)
         )(q, k, v, ps, valid, jnp.int32(0))
@@ -81,7 +81,7 @@ def test_ring_gradients_match(mesh):
         return jnp.sum((o * wmask) ** 2)
 
     g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         g_ring = jax.jit(jax.grad(loss_ring, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(g_ref, g_ring):
         np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
@@ -114,7 +114,7 @@ def test_ring_flash_path_matches_reference(mesh):
     valid = jnp.asarray([64, 50, 64, 40], jnp.int32)
     mask = make_attention_mask(ps, T, valid)
     ref = dot_product_attention(q, k, v, mask=mask, scale=H**-0.5)
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         got = jax.jit(
             lambda *a: ring_attention(
                 *a, scale=H**-0.5, mesh=mesh,
@@ -146,7 +146,7 @@ def test_ring_flash_gradients_match(mesh):
         return jnp.sum((o * wmask) ** 2)
 
     g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         g_ring = jax.grad(loss_ring, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g_ref, g_ring):
         np.testing.assert_allclose(a, b, atol=1e-3, rtol=1e-3)

@@ -238,7 +238,7 @@ def test_prometheus_exposition_carries_slo_and_attribution_series():
     from pilottai_tpu.obs.attribution import DeviceTimeAttributor
 
     attr = DeviceTimeAttributor(registry=registry)
-    attr.configure(flops_per_token=1e9, platform="cpu",
+    attr.configure(flops_per_token=1e9, device_kind="cpu",
                    mesh_axes=("model", "data"))
     attr.record("decode", 0.01, tokens=4)
     text = prometheus_text(metrics_snapshot(registry=registry))
