@@ -124,7 +124,7 @@ from pilottai_tpu.reliability import (
 from pilottai_tpu.reliability import degrade as degrade_levels
 from pilottai_tpu.utils.logging import get_logger
 from pilottai_tpu.utils.metrics import global_metrics
-from pilottai_tpu.utils.tracing import global_tracer
+from pilottai_tpu.utils.tracing import global_tracer, host_span
 
 
 #: Priority-rung names for the per-priority backlog-wait histograms
@@ -283,6 +283,12 @@ class _PreparedAdmission:
     n_prefix_bucket: int = 1
     has_json: bool = False
     has_schema: bool = False
+
+    @property
+    def prefilled(self) -> np.ndarray:
+        """The token array the program prefills: whole prompts, or the
+        tails past a cached prefix. Its shape is the work that runs."""
+        return self.tokens if self.tokens is not None else self.tail_tokens
 
 
 @dataclass
@@ -1547,6 +1553,10 @@ class ContinuousBatcher:
         keep = min(max(keep, 1), self.max_seq_len - 2)
         if len(request.prompt_ids) > keep:
             request.prompt_ids = request.prompt_ids[-keep:]
+        if request.flight_key is not None:
+            global_flight.mark(
+                request.flight_key, "submitted", at=request.submitted_at
+            )
         self._pending.put(request)
         # Gauge on EVERY enqueue, not just admit/fold/shed: a backlog
         # building while the device thread is pinned (e.g. segmenting
@@ -2282,7 +2292,8 @@ class ContinuousBatcher:
                 if self._stop.is_set():
                     break
                 try:
-                    groups, seg, epoch = self._select_groups()
+                    with host_span("prep.select_groups"):
+                        groups, seg, epoch = self._select_groups()
                 except Exception as exc:  # noqa: BLE001 — keep prep alive
                     # A dead prep thread wedges every future admission
                     # (requests queue forever, the breaker opens on the
@@ -2302,9 +2313,12 @@ class ContinuousBatcher:
                     groups, seg = [], None
                 for entry, group in groups:
                     try:
-                        prep = self._prepare_prefill(
-                            group, entry, epoch=epoch
-                        )
+                        with host_span(
+                            "prep.prepare_prefill", requests=len(group)
+                        ):
+                            prep = self._prepare_prefill(
+                                group, entry, epoch=epoch
+                            )
                     except Exception as exc:  # noqa: BLE001 — prep only
                         self._log.error(
                             "admission prep failed: %s", exc, exc_info=True
@@ -2598,7 +2612,9 @@ class ContinuousBatcher:
                 seg_tokens = np.zeros((1, seg), np.int32)
                 seg_tokens[0] = req.prompt_ids[done: done + seg]
                 t_seg = time.perf_counter()
-                with global_metrics.timer("engine.prefill_latency"):
+                with host_span(
+                    "batcher.dispatch_prefill.segment", rows=1, bucket=seg,
+                ), global_metrics.timer("engine.prefill_latency"):
                     self.cache = extend_prompt_paged(
                         self.params, self.cfg, self.cache,
                         jnp.asarray(pages_arr), jnp.int32(done),
@@ -2608,6 +2624,9 @@ class ContinuousBatcher:
                     )
                 global_metrics.inc("engine.prefill_segments")
                 if not self._warming:
+                    # A segment is one unpadded row: real and run agree.
+                    global_metrics.inc("engine.prefill_tokens_real", seg)
+                    global_metrics.inc("engine.prefill_tokens_run", seg)
                     seg_dur = time.perf_counter() - t_seg
                     self._record_attributed(
                         "prefill", seg_dur, seg,
@@ -2792,6 +2811,14 @@ class ContinuousBatcher:
         prefill is enqueued on the device stream BEHIND whatever decode
         chunks are already in flight — chunked-prefill segments and
         decode interleave with no host-side bubble between them."""
+        rows, bucket = prep.prefilled.shape
+        with host_span(
+            f"batcher.dispatch_prefill.{prep.kind}", rows=rows,
+            bucket=bucket, requests=len(prep.group),
+        ):
+            self._dispatch_prefill_traced(prep)
+
+    def _dispatch_prefill_traced(self, prep: _PreparedAdmission) -> None:
         group = prep.group
         entry = prep.entry
         # Restored page chains must be pool-resident before this
@@ -2902,6 +2929,12 @@ class ContinuousBatcher:
             # estimate.
             pf_dur = admit_at - t_pf
             pf_tokens = int(prep.meta_i32[AI_LEN].sum())
+            # Fill of this dispatch: the tokens the requests brought
+            # against the padded rows x bucket the program ran.
+            global_metrics.inc("engine.prefill_tokens_real", pf_tokens)
+            global_metrics.inc(
+                "engine.prefill_tokens_run", prep.prefilled.size
+            )
             self._record_attributed(
                 "prefill", pf_dur, pf_tokens,
                 est=(
@@ -3186,10 +3219,11 @@ class ContinuousBatcher:
         # here is not a fresh device round trip.
         hosts = [copy.wait()[0] for _, copy in groups]
         poisoned: List = []
-        with self._lock:
-            emits = self._fold_first_tokens(groups, hosts, poisoned)
-        self._report_poisoned(poisoned)
-        self._fire_stream(emits)
+        with host_span("reader.fold_first_tokens"):
+            with self._lock:
+                emits = self._fold_first_tokens(groups, hosts, poisoned)
+            self._report_poisoned(poisoned)
+            self._fire_stream(emits)
         self._beat()
 
     def _check_finished(self, idx: int) -> None:
@@ -3251,6 +3285,8 @@ class ContinuousBatcher:
             # never re-emitted).
             if req.recovered_tokens:
                 out = req.recovered_tokens + out
+            if req.flight_key is not None:
+                global_flight.mark(req.flight_key, "batcher_done", at=now)
             req.future.set_result(out)
             if req.recovery_attempts:
                 global_metrics.inc("engine.recovered_requests")
@@ -3379,13 +3415,11 @@ class ContinuousBatcher:
         # a wedged collective. Nothing downstream ever observes it; the
         # watchdog's heartbeat staleness is the only detector.
         global_injector.fire("engine.dispatch.hang")
-        # Host-gap telemetry: how long the device sat with NOTHING in
-        # flight between the last fold/feed and this dispatch — the
-        # host-side bubble overlapped admission + non-blocking folds
-        # exist to close. 0 whenever the pipeline still held work (the
-        # device was fed). Host-side approximation: enqueue times stand
-        # in for device occupancy, which co-locates with it at chunk
-        # granularity.
+        # How long the device sat with NOTHING in flight between the
+        # last fold/feed and this dispatch; 0 whenever the pipeline
+        # still held work. A host-side approximation that only the
+        # attribution gauges read: the profiler trace, with this
+        # thread's spans in its host lanes, is what measures idle.
         t_dispatch = time.perf_counter()
         with self._lock:
             idle = self._inflight == 0
@@ -3397,7 +3431,6 @@ class ContinuousBatcher:
             max(0.0, (t_dispatch - max(marks)) * 1e3)
             if idle and marks else 0.0
         )
-        global_metrics.observe("engine.host_gap_ms", gap_ms)
         if gap_ms > 0.0 and not self._warming:
             # Measured device-idle bubble: the live busy-frac gauge is
             # the complement of these over its window.
@@ -3550,6 +3583,18 @@ class ContinuousBatcher:
         with global_metrics.timer("engine.chunk_read_latency"):
             toks_h, valid_h = copies.wait()
             first_hosts = [copy.wait()[0] for _, copy in groups]
+        # The device's result is on the host: what follows is this
+        # thread's own work.
+        with host_span("reader.process_chunk", blocks=n_blocks):
+            self._fold_chunk(
+                toks_h, valid_h, groups, first_hosts, gen_stamp, est, hi,
+                n_blocks, t_dispatch, gap_ms,
+            )
+
+    def _fold_chunk(
+        self, toks_h, valid_h, groups, first_hosts, gen_stamp, est, hi,
+        n_blocks, t_dispatch, gap_ms,
+    ) -> None:
         # Chaos point: poison one slot's folded ids with an out-of-vocab
         # value at the fold boundary (value= the slot index, or True for
         # the first slot that emitted) — drives the containment path a
@@ -3581,9 +3626,10 @@ class ContinuousBatcher:
             # First tokens were sampled before this chunk ran — fold them
             # first so token order inside each slot is right.
             if groups:
-                emits = self._fold_first_tokens(
-                    groups, first_hosts, poisoned
-                )
+                with host_span("reader.fold_first_tokens"):
+                    emits = self._fold_first_tokens(
+                        groups, first_hosts, poisoned
+                    )
             for b in range(B):
                 slot = self._slots[b]
                 if slot is None or gen_stamp[b] != self._gen[b]:
@@ -3692,7 +3738,6 @@ class ContinuousBatcher:
             chunk_blocks=n_blocks,
             blocks_useful=useful_blocks,
             utilization=round(useful_blocks / max(n_blocks, 1), 3),
-            host_gap_ms=round(gap_ms, 3),
             slots_active=slots_active,
             queue_depth=depth,
             page_strip=self.page_strip,
@@ -3733,6 +3778,12 @@ class ContinuousBatcher:
             pf_since = self._prefill_since_fold
             self._prefill_since_fold = 0.0
         if not self._warming:
+            # Fill of this chunk: rows that held a live request against
+            # the rows the program ran, over the blocks that emitted.
+            global_metrics.inc("engine.decode_rows_active", int(blk_any.sum()))
+            global_metrics.inc(
+                "engine.decode_rows_run", self.n_slots * useful_blocks
+            )
             # Decode device-time estimate: the fold-to-fold interval
             # minus the measured idle gap and any prefill enqueue walls
             # inside it (already attributed above). Pipelined chunks make
@@ -4102,9 +4153,11 @@ class ContinuousBatcher:
                             record_fault=False,
                         )
                     self._rebuild_device_state(reason=reason)
-                self._expire_deadlines()
-                self._admit()
-                with self._lock:
+                with host_span("batcher.expire_deadlines"):
+                    self._expire_deadlines()
+                with host_span("batcher.admit"):
+                    self._admit()
+                with host_span("batcher.pick_chunk"), self._lock:
                     useful = self._chunk_useful()
                     if useful:
                         # Scheduling decision: this dispatch's block
@@ -4140,16 +4193,30 @@ class ContinuousBatcher:
                             if self.alloc is not None else None
                         )
                 if useful:
-                    item = self._dispatch_chunk(
-                        self._decode_bucket(bound), n_blocks, est, hi,
-                        table_np,
-                    )
-                    while not self._stop.is_set():
-                        try:
-                            self._results.put(item, timeout=0.5)
-                            break
-                        except queue.Full:
-                            continue
+                    bucket = self._decode_bucket(bound)
+                    with host_span(
+                        "batcher.dispatch_chunk", blocks=n_blocks,
+                        bound=bucket,
+                    ):
+                        item = self._dispatch_chunk(
+                            bucket, n_blocks, est, hi, table_np,
+                        )
+                    try:
+                        self._results.put_nowait(item)
+                    except queue.Full:
+                        # The reader is behind: this thread has nothing
+                        # to do but wait for it. One short span a try:
+                        # a profiler session records only spans that
+                        # begin and end inside it, and a single span
+                        # over a wait of seconds would leave the traced
+                        # slice with nothing from this thread.
+                        while not self._stop.is_set():
+                            try:
+                                with host_span("batcher.results_full"):
+                                    self._results.put(item, timeout=0.05)
+                                break
+                            except queue.Full:
+                                continue
                 else:
                     with self._lock:
                         need_drain = (
@@ -4159,7 +4226,8 @@ class ContinuousBatcher:
                             self._drain_queued = True
                     if need_drain:
                         self._results.put(None)  # reader folds, in order
-                    self._wake.wait(timeout=0.05)
+                    with host_span("batcher.wait_for_work"):
+                        self._wake.wait(timeout=0.05)
                     self._wake.clear()
             except Exception as exc:  # noqa: BLE001 — device loop boundary
                 self._log.error("device loop error: %s", exc, exc_info=True)

@@ -251,6 +251,7 @@ def _head_tile(params, off: int, end: int):
     return jax.lax.slice_in_dim(params["embed"], off, end, axis=0).T
 
 
+@jax.named_scope("unembed")  # the sampler is fused into it
 def fused_greedy_epilogue(
     cfg: ModelConfig, params, h: jax.Array,
     tile: int = EPILOGUE_VOCAB_TILE,
@@ -346,6 +347,7 @@ def release_decode(state: DecodeState, slots: jax.Array) -> DecodeState:
     )
 
 
+@jax.named_scope("attn")
 def _prefix_stats_dense(
     qg: jax.Array,       # [B, K, G, H]
     layer_k: jax.Array,  # [B, K, S, H] (compute dtype, or int8 w/ scales)
@@ -397,6 +399,7 @@ def _prefix_stats_dense(
     return acc.reshape(B, K * G, H), m.reshape(B, K * G), l.reshape(B, K * G)
 
 
+@jax.named_scope("attn")
 def _ring_stats(
     qg: jax.Array,      # [B, K, G, H]
     ring_k: jax.Array,  # [B, K, N, H]
@@ -431,6 +434,7 @@ def _ring_stats(
     return acc.reshape(B, K * G, H), m.reshape(B, K * G), l.reshape(B, K * G)
 
 
+@jax.named_scope("attn")
 def _combine_stats(acc_a, m_a, l_a, acc_b, m_b, l_b):
     """Merge two online-softmax partials over disjoint key sets and
     normalize (final-merge form of ``_merge_stats``)."""
@@ -865,6 +869,7 @@ def _model_drafts(
     return drafts.T                                        # [B, n_draft]
 
 
+@jax.named_scope("attn")
 def _merge_stats(acc_a, m_a, l_a, acc_b, m_b, l_b):
     """Unnormalized online-softmax merge over disjoint key sets (the
     normalizing division happens once, after the last merge)."""
@@ -874,6 +879,7 @@ def _merge_stats(acc_a, m_a, l_a, acc_b, m_b, l_b):
     return acc_a * wa[..., None] + acc_b * wb[..., None], m, l_a * wa + l_b * wb
 
 
+@jax.named_scope("attn")
 def _ragged_stats(
     qg: jax.Array,     # [B, K, G, H] single-position queries
     ks: jax.Array,     # [B, K, N, H] — row r valid iff r < count[b]
@@ -912,6 +918,7 @@ def _ragged_stats(
     return acc, m, l
 
 
+@jax.named_scope("attn")
 def _spec_block_attn(
     qg: jax.Array,       # [B, K, G, D, H] block queries
     layer_k: jax.Array,  # [B, K, Sb, H] bounded prefix panels (None when
@@ -1382,6 +1389,7 @@ def decode_chunk_spec(
 # --------------------------------------------------------------------- #
 
 
+@jax.named_scope("attn")
 def _tail_prefix_attn(
     qg: jax.Array,          # [A, K, G, T, H] tail queries
     pk: jax.Array,          # [K, P, H] shared cached-prefix keys

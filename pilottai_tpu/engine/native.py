@@ -48,6 +48,7 @@ from pilottai_tpu.parallel.mesh import (
 from pilottai_tpu.parallel.sharding import param_shardings
 from pilottai_tpu.reliability import DegradeLadder
 from pilottai_tpu.utils.logging import get_logger
+from pilottai_tpu.utils.tracing import host_span
 
 
 class NativeEngine(LLMBackend):
@@ -531,7 +532,8 @@ class NativeEngine(LLMBackend):
         params = params or GenerationParams()
         start = time.perf_counter()
 
-        request = self._build_request(messages, tools, params)
+        with host_span("handler.render"):  # messages to token ids
+            request = self._build_request(messages, tools, params)
         prompt_ids = request.prompt_ids
         future = self.batcher.submit(request)
         try:
@@ -585,7 +587,8 @@ class NativeEngine(LLMBackend):
             await self.start()
         assert self.batcher is not None
         params = params or GenerationParams()
-        request = self._build_request(messages, tools, params)
+        with host_span("handler.render"):  # messages to token ids
+            request = self._build_request(messages, tools, params)
 
         loop = asyncio.get_running_loop()
         q: "asyncio.Queue[Optional[list]]" = asyncio.Queue()

@@ -247,6 +247,7 @@ def split_step_keys(keys: jax.Array) -> tuple[jax.Array, jax.Array]:
     return new_keys[:, 0], new_keys[:, 1]
 
 
+@jax.named_scope("sampler")
 def sample_core(
     logits: jax.Array,  # [B, V] fp32
     state: SamplingState,

@@ -27,6 +27,7 @@ from pilottai_tpu.models.qmatmul import qmatmul
 from pilottai_tpu.parallel.sharding import with_logical_constraint
 
 
+@jax.named_scope("moe")
 def moe_mlp(
     cfg: Any,                 # ModelConfig (n_experts, n_active_experts, act)
     p: Dict[str, Any],        # layer slice: router [E,X], wg/wu [X,E,F], wd [X,F,E]

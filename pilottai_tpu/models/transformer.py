@@ -53,11 +53,17 @@ def _mlp(
 
         return moe_mlp(cfg, lp["moe"], x, lambda h: _activation(cfg, h))
     p = lp["mlp"]
-    gate = _activation(cfg, qmatmul(x, p["wg"]))
-    up = qmatmul(x, p["wu"])
-    return qmatmul(gate * up, p["wd"]), jnp.zeros((), jnp.float32)
+    with jax.named_scope("mlp"):
+        gate = _activation(cfg, qmatmul(x, p["wg"]))
+        up = qmatmul(x, p["wu"])
+        return qmatmul(gate * up, p["wd"]), jnp.zeros((), jnp.float32)
 
 
+# The ``jax.named_scope`` on each phase's builder (embed, attn, mlp or moe,
+# kv_write, unembed, sampler; here and in engine/decode.py, ops/) names the
+# step programs' operations in a profiler trace. Op metadata only: the
+# programs, and the compile cache's keys, are what they were.
+@jax.named_scope("attn")
 def _qkv(
     cfg: ModelConfig,
     p: Dict[str, Any],
@@ -74,11 +80,13 @@ def _qkv(
     return q, k, v
 
 
+@jax.named_scope("attn")
 def _attn_out(cfg: ModelConfig, p: Dict[str, Any], attn: jax.Array) -> jax.Array:
     B, T = attn.shape[:2]
     return qmatmul(attn.reshape(B, T, cfg.q_dim), p["wo"])
 
 
+@jax.named_scope("embed")
 def _embed(cfg: ModelConfig, params: Dict[str, Any], tokens: jax.Array) -> jax.Array:
     x = params["embed"][tokens]
     if cfg.scale_embed:
@@ -91,6 +99,7 @@ def _rows_at(x: jax.Array, positions: jax.Array) -> jax.Array:
     return jnp.take_along_axis(x, positions[:, None, None], axis=1)[:, 0]
 
 
+@jax.named_scope("unembed")
 def _unembed(cfg: ModelConfig, params: Dict[str, Any], x: jax.Array) -> jax.Array:
     head = params["lm_head"] if "lm_head" in params else params["embed"].T
     # No spec: the logits projection is the plain last-axis contraction,

@@ -189,12 +189,10 @@ def export_completeness(
 _SPAN_PID = 1
 _ENGINE_PID = 2
 
-# Step-record fields exported as counter tracks. host_gap_ms is the
-# device-feed health signal (time the device sat idle waiting on host
-# work before the dispatch — 0 when the pipeline kept it fed).
+# Step-record fields exported as counter tracks.
 _STEP_COUNTERS = (
     "slots_active", "tokens", "queue_depth", "kv_pages_free",
-    "chunk_blocks", "utilization", "host_gap_ms",
+    "chunk_blocks", "utilization",
 )
 
 

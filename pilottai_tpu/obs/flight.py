@@ -12,15 +12,42 @@ thread, token folds on the reader thread), and ``finish`` derives the
 serving metrics and feeds them into ``global_metrics`` histograms:
 
 ===========================  ==========================================
-``request.queue_wait_s``     submit → batcher admission (slot granted)
+``request.queue_wait_s``     start → batcher admission (slot granted)
 ``request.ttft_s``           start → first generated token on the host
 ``request.itl_s``            inter-token latency, observed per fold
 ``request.tpot_s``           (last − first token) / (n − 1)
-``request.e2e_s``            start → finish
+``request.e2e_s``            start → the handler's finish
 ===========================  ==========================================
 
 plus ``request.completed`` / ``request.failed`` counters labelled by the
 finish status in ``request.finished.<status>``.
+
+One timeline per request, one clock (``time.perf_counter``). Each layer
+stamps a mark where the request crosses its boundary, first stamp wins:
+
+=====================  ================================================
+``edge_received``      server.py: headers and body are in
+``handler_entered``    the handler's first ``start()``; ``started`` too
+``submitted``          ``batcher.submit``
+``admitted``           the admission's prefill is dispatched
+first / last token     ``token()`` from the reader thread's folds
+``batcher_done``       the reader thread resolves the request's future
+``handler_returned``   the handler's ``finish()``; ``ended`` too
+``edge_last_byte``     server.py: the reply (or the error) is written
+=====================  ================================================
+
+and ``derived()`` gives every layer its self time, a span less what its
+child spans cover: ``edge_self_s``, ``handler_self_s``,
+``batcher_wait_s`` (submitted → admitted), ``prefill_s`` (admitted →
+first token), ``decode_s`` (first → last token). For a finished HTTP
+flight the five sum to the edge's span (``edge_s``) less the stretch
+between the last token and ``batcher_done`` (``batcher_tail_s``).
+
+The HTTP edge holds the flight it opened (``open_edge``) across the
+handler's ``finish`` and closes it itself (``close_edge``) once the last
+byte is out: metrics are observed and finish listeners fire there, once.
+A caller with no edge (``Serve``, a bare ``LLMHandler``) closes at the
+handler's ``finish`` as before.
 
 Backends that cannot see individual tokens (the mock, pre-token-callback
 custom backends) call ``synthesize_tokens`` with the response envelope —
@@ -59,6 +86,10 @@ class RequestFlight:
     last_token_at: Optional[float] = None
     status: Optional[str] = None  # set by finish()
     ended: Optional[float] = None
+    # The HTTP edge opened this flight and closes it (``close_edge``):
+    # the handler's finish() settles status and ``ended`` but leaves it
+    # active until the reply is written.
+    edge_held: bool = False
 
     def to_dict(self) -> Dict[str, Any]:
         out: Dict[str, Any] = {
@@ -95,7 +126,38 @@ class RequestFlight:
             )
         if self.ended is not None:
             out["e2e_s"] = max(self.ended - self.started, 0.0)
+        out.update(self._self_times())
         return out
+
+    def _self_times(self) -> Dict[str, float]:
+        """Each layer's span less what its child spans cover. A key whose
+        marks are not all there is left out, never reported as 0."""
+        m = self.marks
+
+        def span(a: str, b: str) -> Optional[float]:
+            return m[b] - m[a] if a in m and b in m else None
+
+        out: Dict[str, float] = {}
+        edge = span("edge_received", "edge_last_byte")
+        handler = span("handler_entered", "handler_returned")
+        batcher = span("submitted", "batcher_done")
+        wait = span("submitted", "admitted")
+        if edge is not None:
+            out["edge_s"] = edge
+        if edge is not None and handler is not None:
+            out["edge_self_s"] = edge - handler
+        if handler is not None and batcher is not None:
+            out["handler_self_s"] = handler - batcher
+        if wait is not None:
+            out["batcher_wait_s"] = wait
+        if "admitted" in m and self.first_token_at is not None:
+            out["prefill_s"] = self.first_token_at - m["admitted"]
+        if self.first_token_at is not None and self.last_token_at is not None:
+            out["decode_s"] = self.last_token_at - self.first_token_at
+        if self.last_token_at is not None and "batcher_done" in m:
+            out["batcher_tail_s"] = m["batcher_done"] - self.last_token_at
+        # Marks out of order (a retry re-entered a phase) give no self time.
+        return {k: v for k, v in out.items() if v >= 0.0}
 
 
 class FlightRecorder:
@@ -142,6 +204,22 @@ class FlightRecorder:
     # Lifecycle (handler / HTTP edge)
     # ------------------------------------------------------------------ #
 
+    def open_edge(
+        self, flight_id: str, trace_id: str, received_at: float
+    ) -> None:
+        """The HTTP edge opens the request's flight before it calls the
+        handler, and holds it: the handler's ``finish`` settles the
+        status, ``close_edge`` closes it. ``received_at`` is when the
+        request's headers and body were in."""
+        with self._lock:
+            if flight_id in self._active:
+                return
+            flight = RequestFlight(
+                flight_id=flight_id, trace_id=trace_id, edge_held=True
+            )
+            flight.marks["edge_received"] = received_at
+            self._active[flight_id] = flight
+
     def start(
         self,
         flight_id: str,
@@ -149,10 +227,12 @@ class FlightRecorder:
         **attributes: Any,
     ) -> RequestFlight:
         """Get-or-create the active flight for ``flight_id`` (idempotent:
-        the server may open it before the handler enriches it).
-        ``trace_id`` defaults to the flight id for callers with a
-        one-request trace (the HTTP edge)."""
-        created = False
+        the edge or the cell may open it before the handler enriches
+        it). The first call is the request entering the handler: it
+        stamps ``handler_entered`` and, on a flight the edge opened,
+        moves ``started`` there, so that ``ttft_s`` and ``e2e_s`` start
+        where they always did. ``trace_id`` defaults to the flight id
+        for callers with a one-request trace."""
         with self._lock:
             flight = self._active.get(flight_id)
             if flight is None:
@@ -160,9 +240,13 @@ class FlightRecorder:
                     flight_id=flight_id, trace_id=trace_id or flight_id
                 )
                 self._active[flight_id] = flight
-                created = True
+            entered = "handler_entered" not in flight.marks
+            if entered:
+                if flight.edge_held:
+                    flight.started = time.perf_counter()
+                flight.marks["handler_entered"] = flight.started
             flight.attributes.update(attributes)
-        if created:
+        if entered:
             for listener in self._start_listeners:
                 try:
                     listener(flight)
@@ -171,11 +255,44 @@ class FlightRecorder:
         return flight
 
     def finish(self, flight_id: str, status: str = "ok") -> Optional[Dict[str, Any]]:
-        """Close the flight: derive phase metrics, observe them into the
-        registry, move the record to the finished ring. Returns the
-        flight's summary dict, or None when no active flight exists
-        (already finished, or never started) — safe to call from every
-        error path without bookkeeping.
+        """The handler is done with the request: settle status and
+        ``ended`` (the ``handler_returned`` mark) and, unless the HTTP
+        edge holds the flight, close it. Returns the flight's summary
+        dict, or None when no active flight exists (already finished,
+        or never started) — safe to call from every error path without
+        bookkeeping."""
+        with self._lock:
+            flight = self._active.get(flight_id)
+            if flight is None or flight.ended is not None:
+                return None
+            flight.status = status
+            flight.ended = time.perf_counter()
+            flight.marks.setdefault("handler_returned", flight.ended)
+            if flight.edge_held:
+                return flight.to_dict()
+        return self._close(flight_id)
+
+    def close_edge(self, flight_id: str, status: str) -> None:
+        """The HTTP edge has written the reply's last byte, or given up
+        on a client that went away (``status`` then says so and
+        overrides the handler's ``ok``; a failure the handler settled
+        stands). A request that never reached the handler is settled
+        here with ``status``."""
+        now = time.perf_counter()
+        with self._lock:
+            flight = self._active.get(flight_id)
+            if flight is None:
+                return
+            flight.marks.setdefault("edge_last_byte", now)
+            if flight.ended is None:
+                flight.ended = now
+            if flight.status in (None, "ok"):
+                flight.status = status
+        self._close(flight_id)
+
+    def _close(self, flight_id: str) -> Optional[Dict[str, Any]]:
+        """Move the settled flight to the finished ring, observe its
+        phase metrics and fire the finish listeners, once.
 
         Phase histograms are observed for ``ok`` flights ONLY: a storm
         of shed/breaker-fast-fails would otherwise flood the (window-
@@ -185,9 +302,8 @@ class FlightRecorder:
             flight = self._active.pop(flight_id, None)
             if flight is None:
                 return None
-            flight.status = status
-            flight.ended = time.perf_counter()
             self._finished.append(flight)
+        status = flight.status
         if status == "ok":
             for name, value in flight.derived().items():
                 self._registry.observe(f"request.{name}", value)

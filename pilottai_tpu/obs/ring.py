@@ -12,12 +12,9 @@ Record shape (by ``kind``):
 ``engine.chunk``   one fused decode chunk folded on the host — slot
                    occupancy, tokens landed, dispatched block count
                    (``chunk_blocks``, the adaptive scheduler's per-
-                   dispatch pick) and useful-block utilization, the
-                   dispatch's ``host_gap_ms`` (device idle time between
-                   the previous fold/feed and this dispatch; 0 = the
-                   pipeline kept the device fed), queue depth, KV
-                   page-pool utilization, active strip width, pipeline
-                   depth.
+                   dispatch pick) and useful-block utilization, queue
+                   depth, KV page-pool utilization, active strip width,
+                   pipeline depth.
 ``engine.admit``   one admission wave — group size, queue depth.
 ``engine.shed``    an admission-control shed.
 ``handler.request`` one completed/failed LLMHandler request — status,

@@ -56,6 +56,7 @@ def flash_shapes_ok(
     return kv_bytes <= _FLASH_KV_VMEM_BUDGET
 
 
+@jax.named_scope("attn")
 def dot_product_attention(
     q: jax.Array,  # [B, T, N, H]
     k: jax.Array,  # [B, S, K, H]

@@ -114,6 +114,7 @@ class KVCache(NamedTuple):
         )
 
 
+@jax.named_scope("kv_write")
 def write_prompts(
     cache: KVCache,
     slots: jax.Array,      # [A] int32 — target slot per admitted prompt
@@ -170,6 +171,7 @@ def write_prompts(
     )
 
 
+@jax.named_scope("kv_write")
 def write_chunk_rows(
     cache: KVCache,
     ring_ks,               # list per layer: [B, K, n, H] chunk ring

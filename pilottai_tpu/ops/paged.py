@@ -204,6 +204,7 @@ class PageAllocator:
         return len(self.free)
 
 
+@jax.named_scope("kv_write")
 def write_prompts_paged(
     cache: PagedKVCache,
     table: jax.Array,     # [A, max_pages] int32 — page rows of the admitted
@@ -278,6 +279,7 @@ def install_lengths(
     )
 
 
+@jax.named_scope("kv_write")
 def write_chunk_rows_paged(
     cache: PagedKVCache,
     table: jax.Array,     # [B, max_pages] int32 — full block table

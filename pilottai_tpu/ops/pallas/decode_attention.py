@@ -185,6 +185,7 @@ def decode_attention(
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="decode_attention",
     )(last_valid, q_positions, qg, k_cache, v_cache)
     if return_stats:
         acc, m, l = out

@@ -189,6 +189,7 @@ def _fwd_impl(
             jax.ShapeDtypeStruct((B, N, T, 1), jnp.float32),
         ),
         interpret=interpret,
+        name="flash_attention",
     )(window, valid, qpos, kpos, q_t, k_t, v_t)
     return o_t.transpose(0, 2, 1, 3), lse                    # o [B,T,N,H]; lse [B,N,T,1]
 
@@ -405,6 +406,7 @@ def _bwd_impl(
         grid_spec=dq_spec,
         out_shape=jax.ShapeDtypeStruct(q_t.shape, q.dtype),
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(window, valid, qpos, kpos, q_t, k_t, v_t, do_t, lse, delta)
 
     dkv_kernel = functools.partial(
@@ -445,6 +447,7 @@ def _bwd_impl(
             jax.ShapeDtypeStruct(v_t.shape, jnp.float32),
         ),
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(window, valid, qpos, kpos, q_t, k_t, v_t, do_t, lse, delta)
 
     dq = dq_t.transpose(0, 2, 1, 3)

@@ -358,6 +358,7 @@ def paged_decode_attention(
             jax.ShapeDtypeStruct((B, K, G, 1), jnp.float32),
         ),
         interpret=interpret,
+        name="paged_decode_attention",
     )(*scalars, *operands)
     return acc.reshape(B, N, H), m.reshape(B, N), l.reshape(B, N)
 

@@ -63,7 +63,7 @@ from typing import Any, Dict, List, Optional, Tuple
 from urllib.parse import parse_qs
 
 from pilottai_tpu.engine.types import GenerationParams, ToolSpec
-from pilottai_tpu.obs import metrics_snapshot, prometheus_text
+from pilottai_tpu.obs import global_flight, metrics_snapshot, prometheus_text
 from pilottai_tpu.reliability import (
     CircuitOpenError,
     DeadlineExceeded,
@@ -71,7 +71,7 @@ from pilottai_tpu.reliability import (
 )
 from pilottai_tpu.utils.logging import get_logger
 from pilottai_tpu.utils.metrics import global_metrics
-from pilottai_tpu.utils.tracing import global_tracer
+from pilottai_tpu.utils.tracing import global_tracer, host_span
 
 # Client-supplied x-request-id values become trace ids threaded through
 # logs, span trees and black-box dumps — constrain the alphabet so a
@@ -102,6 +102,32 @@ class _HttpError(Exception):
         self.message = message
         self.kind = kind
         self.extra = extra or {}
+
+
+class _EdgeFlight:
+    """The edge's hold on one connection's request flight
+    (obs/flight.py). ``_handle_conn`` stamps ``received_at`` once
+    headers and body are in; ``_chat_completions`` opens the flight
+    before it calls the handler; ``_handle_conn`` closes it after the
+    reply, or the error body, is written. ``outcome`` is the edge's own
+    verdict, which stands only where the handler settled ``ok`` or
+    nothing: a connection that ends without one was cut off."""
+
+    __slots__ = ("received_at", "flight_id", "outcome")
+
+    def __init__(self) -> None:
+        self.received_at = 0.0
+        self.flight_id: Optional[str] = None
+        self.outcome = "cancelled"
+
+    def open(self, trace_id: str) -> str:
+        self.flight_id = uuid.uuid4().hex[:16]
+        global_flight.open_edge(self.flight_id, trace_id, self.received_at)
+        return self.flight_id
+
+    def close(self) -> None:
+        if self.flight_id is not None:
+            global_flight.close_edge(self.flight_id, self.outcome)
 
 
 def _overload_error(exc: Exception) -> _HttpError:
@@ -189,6 +215,7 @@ class APIServer:
     async def _handle_conn(
         self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter
     ) -> None:
+        edge = _EdgeFlight()
         try:
             try:
                 method, path, query, headers, body = await self._read_request(
@@ -197,16 +224,22 @@ class APIServer:
             except _HttpError as exc:
                 await self._send_error(writer, exc)
                 return
+            edge.received_at = time.perf_counter()
             try:
                 self._check_auth(path, headers)
-                await self._route(method, path, query, headers, body, writer)
+                await self._route(
+                    method, path, query, headers, body, writer, edge
+                )
+                edge.outcome = "ok"
             except _HttpError as exc:
                 await self._send_error(writer, exc)
+                edge.outcome = "error"
             except (DeadlineExceeded, EngineOverloaded, CircuitOpenError) as exc:
                 # Overload/deadline shedding is routine under load — a
                 # structured client error, not a 500 with a stack trace.
                 global_metrics.inc("server.shed_responses")
                 await self._send_error(writer, _overload_error(exc))
+                edge.outcome = "error"
             except (ConnectionError, asyncio.IncompleteReadError):
                 # Routine client drop (usually mid-SSE): no error log, and
                 # never write a 500 body into an already-started response.
@@ -216,9 +249,13 @@ class APIServer:
                 await self._send_error(
                     writer, _HttpError(500, "internal error", "server_error")
                 )
+                edge.outcome = "error"
         except (ConnectionError, asyncio.IncompleteReadError):
             pass  # client went away
         finally:
+            # The flight's last mark, after whatever was written: a
+            # dropped client closes it too.
+            edge.close()
             try:
                 writer.close()
                 await writer.wait_closed()
@@ -285,13 +322,28 @@ class APIServer:
         payload: Dict[str, Any],
         extra_headers: Optional[Dict[str, str]] = None,
     ) -> None:
-        await self._send_raw(
-            writer, status, json.dumps(payload).encode(),
-            "application/json", extra_headers,
-        )
+        # Payload to bytes and the write, without the drain: a host-lane
+        # span holds no wait, and none is left open across an await.
+        with host_span("edge.write"):
+            self._write(
+                writer, status, json.dumps(payload).encode(),
+                "application/json", extra_headers,
+            )
+        await writer.drain()
 
     async def _send_raw(
         self,
+        writer: asyncio.StreamWriter,
+        status: int,
+        data: bytes,
+        ctype: str,
+        extra_headers: Optional[Dict[str, str]] = None,
+    ) -> None:
+        self._write(writer, status, data, ctype, extra_headers)
+        await writer.drain()
+
+    @staticmethod
+    def _write(
         writer: asyncio.StreamWriter,
         status: int,
         data: bytes,
@@ -306,7 +358,6 @@ class APIServer:
         for key, value in (extra_headers or {}).items():
             head += f"{key}: {value}\r\n"
         writer.write(head.encode() + b"Connection: close\r\n\r\n" + data)
-        await writer.drain()
 
     async def _send_error(self, writer: asyncio.StreamWriter, exc: _HttpError) -> None:
         await self._send(
@@ -335,7 +386,8 @@ class APIServer:
 
     @staticmethod
     def _sse_event(writer: asyncio.StreamWriter, payload: Dict[str, Any]) -> None:
-        writer.write(("data: " + json.dumps(payload) + "\n\n").encode())
+        with host_span("edge.write"):
+            writer.write(("data: " + json.dumps(payload) + "\n\n").encode())
 
     def _sse_error(self, writer: asyncio.StreamWriter, exc: Exception) -> None:
         """In-band error event: the 200 + SSE status line is already on
@@ -372,6 +424,7 @@ class APIServer:
         headers: Dict[str, str],
         body: bytes,
         writer: asyncio.StreamWriter,
+        edge: Optional[_EdgeFlight] = None,
     ) -> None:
         if path == "/healthz" and method == "GET":
             # Liveness AND engine liveness: a watchdog-declared stall (a
@@ -495,7 +548,9 @@ class APIServer:
         elif path == "/v1/chat/completions":
             if method != "POST":
                 raise _HttpError(405, "POST required")
-            await self._chat_completions(_parse_json(body), writer, headers)
+            with host_span("edge.parse"):
+                req = _parse_json(body)
+            await self._chat_completions(req, writer, headers, edge)
         elif path == "/v1/embeddings":
             if method != "POST":
                 raise _HttpError(405, "POST required")
@@ -747,34 +802,38 @@ class APIServer:
         req: Dict[str, Any],
         writer: asyncio.StreamWriter,
         headers: Optional[Dict[str, str]] = None,
+        edge: Optional[_EdgeFlight] = None,
     ) -> None:
         trace_id = self._trace_id(headers)
         # Root span of the request's trace: the handler's engine.generate
         # span nests under it (same asyncio task), the batcher's emitted
-        # span under that — one tree, server → handler → batcher.
+        # span under that — one tree, server → handler → batcher. It
+        # stays out of the profiler's host lanes (utils/tracing.py
+        # host_span): it would cover every gap of the request's seconds.
         with global_tracer.span(
             "server.request", trace_id=trace_id,
             route="/v1/chat/completions",
         ):
-            await self._chat_completions_traced(req, writer, headers, trace_id)
+            await self._chat_completions_traced(
+                req, writer, headers, trace_id, edge
+            )
 
-    async def _chat_completions_traced(
-        self,
-        req: Dict[str, Any],
-        writer: asyncio.StreamWriter,
-        headers: Optional[Dict[str, str]],
-        trace_id: str,
-    ) -> None:
+    def _chat_params(
+        self, req: Dict[str, Any], headers: Dict[str, str], trace_id: str,
+    ) -> Tuple[Any, List[Any], Optional[List[ToolSpec]], GenerationParams, str]:
+        """Body and headers to the handler's arguments: ``(handler,
+        messages, tools, params, model)``. Raises ``_HttpError`` for
+        what this deployment cannot serve."""
         messages, tools, params, strict = self._gen_params(req)
         handler = self._pick_handler(req.get("model"))
-        deadline = self._request_deadline(req, headers or {}, handler)
+        deadline = self._request_deadline(req, headers, handler)
         params = params.model_copy(update={"trace_id": trace_id})
         if deadline is not None:
             params = params.model_copy(update={"deadline": deadline})
         slo_class = self._slo_class(req, headers)
         if slo_class is not None:
             params = params.model_copy(update={"slo_class": slo_class})
-        session_id = self._session_id(req, headers or {})
+        session_id = self._session_id(req, headers)
         if session_id is not None:
             params = params.model_copy(update={"session_id": session_id})
         priority = self._priority(req, headers)
@@ -799,6 +858,26 @@ class APIServer:
                     400, f"response_format json_schema with strict=true "
                     f"is not enforceable here: {reason}"
                 )
+        return handler, messages, tools, params, model
+
+    async def _chat_completions_traced(
+        self,
+        req: Dict[str, Any],
+        writer: asyncio.StreamWriter,
+        headers: Optional[Dict[str, str]],
+        trace_id: str,
+        edge: Optional[_EdgeFlight] = None,
+    ) -> None:
+        with host_span("edge.parse"):
+            handler, messages, tools, params, model = self._chat_params(
+                req, headers or {}, trace_id
+            )
+        if edge is not None:
+            # The request is the handler's from here: open its flight
+            # (closed by _handle_conn once the reply is written).
+            params = params.model_copy(
+                update={"flight_id": edge.open(trace_id)}
+            )
         rid = f"chatcmpl-{uuid.uuid4().hex[:24]}"
         created = int(time.time())
 

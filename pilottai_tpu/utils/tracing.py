@@ -1,10 +1,12 @@
-"""Span tracing threaded through task → agent → engine, with jax.profiler
-integration on device-side spans.
+"""Span tracing threaded through task → agent → engine, and the host-lane
+spans that put the engine's threads on the profiler's clock.
 
 The reference has no tracing at all (SURVEY.md §5.1 — only ad-hoc
-``execution_time`` stamps). Here every task execution opens a span tree;
-device spans additionally emit ``jax.profiler.TraceAnnotation`` markers so
-steps line up with XLA traces in TensorBoard.
+``execution_time`` stamps). Here every task execution opens a span tree
+(``Tracer``, kept in memory for black-box dumps). ``host_span`` is apart
+from it: a ``jax.profiler.TraceAnnotation`` and nothing else, so that a
+profiler trace shows what each host thread was doing beside the device's
+operations.
 """
 
 from __future__ import annotations
@@ -73,7 +75,6 @@ class Tracer:
     def span(
         self,
         name: str,
-        device: bool = False,
         trace_id: Optional[str] = None,
         **attributes: Any,
     ) -> Iterator[Span]:
@@ -93,17 +94,8 @@ class Tracer:
             attributes=attributes,
         )
         token = self._stack_var.set(self._stack_var.get() + (span,))
-        annotation = contextlib.nullcontext()
-        if device:
-            try:
-                import jax.profiler
-
-                annotation = jax.profiler.TraceAnnotation(name)
-            except Exception:  # pragma: no cover - profiler optional
-                pass
         try:
-            with annotation:
-                yield span
+            yield span
         finally:
             span.end = time.perf_counter()
             self._stack_var.reset(token)
@@ -162,3 +154,20 @@ class Tracer:
 
 
 global_tracer = Tracer()
+
+
+def host_span(name: str, **sizes: Any) -> Any:
+    """A span in the profiler's host lanes, on the device trace's clock:
+    a context manager over ``jax.profiler.TraceAnnotation``. It records
+    nothing in any ``Tracer`` and costs an inactive ``TraceMe`` while no
+    profiler session runs.
+
+    A host-lane span holds host work, or the device thread's wait for
+    requests, and never a wait on another layer: the trace reducer names
+    a device-idle gap by the span that covers most of it. ``name`` is a
+    fixed string (the reducer keys by it); sizes (rows, bucket, blocks)
+    go as keyword arguments, which the trace shows as the event's
+    arguments."""
+    import jax.profiler
+
+    return jax.profiler.TraceAnnotation(name, **sizes)
