@@ -241,8 +241,10 @@ class LLMConfig(BaseModel):
     # dispatch automatically).
     engine_fused_epilogue: bool = True
     engine_slots: int = Field(default=8, ge=1)       # continuous-batching slots
-    # Admission group width: prompts prefilled per fused admission
-    # dispatch (padded to this, so compile variants stay bounded). A full
+    # Admission group width: the MOST prompts one fused admission
+    # dispatch prefills. A dispatch runs the smallest power-of-two row
+    # count that holds its group (1, 2, 4, ... up to this), so compile
+    # variants stay bounded and a lone request runs one row. A full
     # 32-slot wave admits in ceil(32/width) dispatches.
     engine_admit_batch: int = Field(default=8, ge=1)
     engine_max_seq: Optional[int] = None             # KV length cap (default model max)

@@ -22,10 +22,11 @@ batcher multiplexes them onto fixed-shape device computations:
   tokens while chunks N and N+1 compute, so even the once-per-chunk sync
   overlaps device work;
 * admissions happen between chunks in **batched groups**: one prefill for
-  up to ``admit_batch`` prompts (padded to a fixed group size so compile
-  variants stay bounded), KV written by one batched scatter, first token
-  sampled on device with the slot's own sampling params (no host-side
-  sampling duplicate);
+  up to ``admit_batch`` prompts (its rows follow the group up a
+  power-of-two ladder, ``_row_bucket``: compile variants stay bounded
+  and a lone request does not run a full group's rows), KV written by
+  one batched scatter, first token sampled on device with the slot's
+  own sampling params (no host-side sampling duplicate);
 * admission **prep is overlapped** (PERF_NOTES round 8): bucket/slot
   selection, page allocation, prefix matching and staging-buffer
   packing run on a dedicated prep thread (``_prep_loop``), so between
@@ -673,6 +674,14 @@ class ContinuousBatcher:
             -(-prefill_chunk // page_size) * page_size
             if paged and prefill_chunk > 0 else 0
         )
+        # The longest prompt a monolithic (group) prefill takes: past it
+        # prompts segment. Top of warmup's bucket sweep, and the one
+        # bucket whose groups get the row ladder (_row_bucket).
+        self.full_prefill_cap = self.max_seq_len
+        if self.prefill_chunk:
+            self.full_prefill_cap = min(
+                self.max_seq_len, 2 * self.prefill_chunk
+            )
         # In-flight segmented admission: [slot_idx, request, tokens_done]
         # (device thread only; the slot is excluded from free lists until
         # the final segment installs it). _seg_epoch is the allocator
@@ -1040,6 +1049,13 @@ class ContinuousBatcher:
         )
         self.kv_heads_sharded = kv_axes["heads"] is not None
         self.data_groups = int(kv_axes["data_groups"])
+        # Fewest rows an admission dispatch may have (_row_bucket): the
+        # sharded flash prefill splits the batch over the data axes and
+        # takes the dense fallback when they do not divide it
+        # (flash_sharding_ok), so on a mesh the row ladder starts at
+        # their product, not at 1.
+        shape = dict(self.mesh.shape) if self.mesh is not None else {}
+        self.row_floor = int(shape.get("data", 1) * shape.get("fsdp", 1))
         # The dense Pallas decode kernel (opt-in A/B path,
         # PILOTTAI_DECODE_PALLAS) has no shard_map wrapper: on a mesh
         # whose dense panels shard it cannot lower per-shard — demote
@@ -1307,11 +1323,14 @@ class ContinuousBatcher:
             )
 
     def warmup(self, prompt_lens: Optional[Tuple[int, ...]] = None) -> None:
-        """Compile the admission path for EVERY prefill bucket plus the
-        decode chunk up front, so steady-state serving never waits on the
-        compiler. Groups are padded to ``admit_batch``, so one request per
-        bucket compiles the same batched write/sample/admit shapes a full
-        production wave hits.
+        """Compile the admission path for EVERY prefill bucket at every
+        row count its groups can run at, plus the decode chunk, up
+        front, so steady-state serving never waits on the compiler. A
+        full prefill has ``_row_bucket(len(group), bucket)`` rows, so
+        each bucket is swept once per rung (one below the top bucket,
+        the whole ladder at it) with a wave of that many requests
+        submitted together: the same batched write/sample/admit shapes
+        any production group of 1..``admit_batch`` hits.
 
         With chunked prefill active, buckets past the segmentation
         threshold never run as monolithic group prefills at serve time —
@@ -1319,11 +1338,10 @@ class ContinuousBatcher:
         prefill executable alone exceeds a v5e's HBM next to 8B int8
         weights (measured: 17.97G of 15.75G). Instead the sweep stops at
         the threshold and one long prompt warms the segment ladder
-        (extend_prompt_paged variants + the final tail admission)."""
+        (extend_prompt_paged variants + the final tail admission, one
+        row each)."""
         if prompt_lens is None:
-            cap = self.max_seq_len
-            if self.prefill_chunk:
-                cap = min(cap, 2 * self.prefill_chunk)
+            cap = self.full_prefill_cap
             prompt_lens = tuple(sorted(
                 {self._bucket(n) for n in range(1, cap + 1)}
             ))
@@ -1341,22 +1359,65 @@ class ContinuousBatcher:
             # warmup request per chunk bucket (pinned via _force_chunk —
             # the policy alone would pick the smallest bucket for these
             # 2-token requests), so a serve-time bucket switch never
-            # waits on the compiler. Prompt ids shift per pass so the
+            # waits on the compiler. Prompt ids shift per request so the
             # repeats don't short-circuit into the prefix-cache tail
             # path, which would skip the full-prefill compile.
             for plen in prompt_lens:
                 plen = min(plen, self.max_seq_len - 8)
                 for ci, cb in enumerate(self.chunk_buckets):
                     self._force_chunk = cb
-                    req = GenRequest(
-                        prompt_ids=list(range(2 + ci, 2 + ci + plen)),
-                        max_new_tokens=2,
+                    self._warm_wave(plen, 1, shift=ci)
+                if plen > self.full_prefill_cap:
+                    continue  # segmented: never a group, always one row
+                # The lone requests above ran the bucket's lowest rung;
+                # the decode grid does not depend on the rows admitted.
+                bucket = self._bucket(plen)
+                rungs = sorted({
+                    self._row_bucket(n, bucket)
+                    for n in range(1, self.admit_batch + 1)
+                })
+                for rows in rungs[1:]:
+                    self._warm_wave(
+                        plen, rows, shift=len(self.chunk_buckets)
                     )
-                    self.submit(req)
-                    req.future.result(timeout=900)
         finally:
             self._warming = False
             self._force_chunk = None
+
+    def _warm_wave(self, plen: int, n: int, shift: int) -> None:
+        """Admit ``n`` warmup prompts of ``plen`` tokens as ONE group and
+        wait for them."""
+        reqs = [
+            GenRequest(
+                prompt_ids=list(range(2 + shift + i, 2 + shift + i + plen)),
+                max_new_tokens=2,
+            )
+            for i in range(n)
+        ]
+        self._submit_together(reqs)
+        for req in reqs:
+            req.future.result(timeout=900)
+
+    def _submit_together(self, reqs: List[GenRequest]) -> None:
+        """Submit ``reqs`` so that one selection takes them all (they
+        still split by prefix hit, pages and ``admit_batch``). Under the
+        slot lock, which selection holds from its drain of the
+        submission queue on, so no selection sees part of them; and only
+        once as many slots are selectable (a finished slot is not until
+        the device thread's next cycle), or the group would split at the
+        last free slot. Warmup's waves, whose rung would otherwise stay
+        uncompiled; after 5 s they go in as they are."""
+        give_up = time.monotonic() + 5.0
+        while True:
+            with self._lock:
+                if (
+                    len(self._selectable_slots_locked()) >= len(reqs)
+                    or time.monotonic() >= give_up
+                ):
+                    for req in reqs:
+                        self.submit(req)
+                    return
+            time.sleep(0.002)
 
     # ------------------------------------------------------------------ #
     # Submission (any thread)
@@ -1592,6 +1653,29 @@ class ContinuousBatcher:
             b *= 2
         return b
 
+    def _row_bucket(self, n: int, bucket: Optional[int] = None) -> int:
+        """Rows of an admission dispatch that holds ``n`` requests: the
+        smallest rung of a power-of-two ladder from ``row_floor`` to
+        ``admit_batch`` (1, 2, 4, 8 on one chip at the default) — a row
+        is a whole forward pass over the bucket, so a lone request does
+        not pay for a full group's.
+
+        ``bucket`` is the token bucket of a FULL prefill, whose programs
+        warmup() builds up front, every rung of every bucket, and each
+        one costs its trace and its executable's load at every start
+        (3.4 s a program at 7B with a warm compile cache, measured). So
+        only the top bucket, ``full_prefill_cap``, whose row is the
+        costliest, gets the ladder; groups at smaller buckets pad to
+        ``admit_batch`` (at most half as many tokens as a full group of
+        the top bucket). Prefix-hit admissions pass no bucket and use
+        every rung: their programs are built when first met."""
+        if bucket is not None and bucket < self.full_prefill_cap:
+            return self.admit_batch
+        rows = self.row_floor
+        while rows < n:
+            rows *= 2
+        return min(rows, self.admit_batch)
+
     def _prefix_hit(self, req: GenRequest):
         """Prefix-store match that also fits: the tail write lands at
         [prefix_len, prefix_len + tail_bucket) and dynamic_update_slice
@@ -1659,6 +1743,19 @@ class ContinuousBatcher:
         executable variants stay O(log S)). Sharing the ladder means
         warmup's prefill sweep compiles every decode variant too."""
         return max(self._bucket(n), min(128, self.max_seq_len))
+
+    def _selectable_slots_locked(self) -> List[int]:
+        """Slots an admission may take now. A slot completed but not
+        yet device-released is not yet admissible: its release ops
+        (decode stop, page free) run next device cycle, and admitting
+        into it now would let that stale release wipe the new occupant.
+        One cycle of patience. Slots a previous selection reserved
+        (prepared admission not yet installed) are off the table too."""
+        not_yet = set(self._release)
+        return [
+            i for i in self._free_slot_indices()
+            if i not in not_yet and i not in self._prep_reserved
+        ]
 
     def _free_slot_indices(self) -> List[int]:
         free = [i for i, s in enumerate(self._slots) if s is None]
@@ -2357,23 +2454,17 @@ class ContinuousBatcher:
         failure so overlapping selections can't double-book them."""
         seg = None
         with self._lock:
+            # Drained under the lock (the callers' own drain only keeps
+            # their idle checks cheap): requests submitted under it —
+            # warmup's waves — reach the backlog all together or not yet.
+            self._drain_pending()
             # DAG-aware ordering first (policy-gated; warmup keeps the
             # compile sweep's deterministic submission order): priority
             # + aging floor + gang grouping decide who the "head" is.
             if self.sched_policy == "dag" and not self._warming:
                 self._order_backlog_locked()
-            # A slot completed but not yet device-released is not yet
-            # admissible: its release ops (decode stop, page free) run
-            # next device cycle, and admitting into it now would let
-            # that stale release wipe the new occupant. One cycle of
-            # patience. Slots a previous selection reserved (prepared
-            # admission not yet installed) are off the table too.
             epoch = self._alloc_epoch
-            not_yet = set(self._release)
-            free = [
-                i for i in self._free_slot_indices()
-                if i not in not_yet and i not in self._prep_reserved
-            ]
+            free = self._selectable_slots_locked()
             # Degrade rung 3+ (reliability/degrade.py): cap live
             # occupancy at half the slots — less work in flight per
             # fault, faster drains, smaller recovery replays.
@@ -2643,14 +2734,15 @@ class ContinuousBatcher:
                 return
             # Final segment: the tokens already written are this slot's
             # own page chain — admit exactly like a block-prefix hit, at
-            # n_rows=1 (admit_batch padding rows against an 8K chain
-            # made the prefix-score tensor 8x bigger for nothing — a
-            # measured compile OOM). Re-reserve the slot across the
-            # handoff: segmentation ends here but the slot is not
-            # installed until _dispatch_prefill, and the prep thread
-            # (woken by _end_segmentation) must not select an empty slot
-            # that still holds this request's pages. Install (or the
-            # failure path below) clears the reservation.
+            # one row whatever the mesh's row floor (every padding row
+            # against an 8K chain multiplies the prefix-score tensor —
+            # at admit_batch rows a measured compile OOM). Re-reserve
+            # the slot across the handoff: segmentation ends here but
+            # the slot is not installed until _dispatch_prefill, and the
+            # prep thread (woken by _end_segmentation) must not select
+            # an empty slot that still holds this request's pages.
+            # Install (or the failure path below) clears the
+            # reservation.
             with self._lock:
                 self._prep_reserved.add(idx)
             self._end_segmentation()
@@ -2702,7 +2794,10 @@ class ContinuousBatcher:
         per-field ``jnp.asarray`` uploads this replaces each paid a
         transfer-setup/dispatch floor. No device work
         happens here — that is the point."""
-        A = n_rows if n_rows is not None else self.admit_batch
+        T = self._bucket(max(len(r.prompt_ids) for _, r in group))
+        A = n_rows if n_rows is not None else self._row_bucket(
+            len(group), T if entry is None else None
+        )
         mi, mf = pack_admit_meta(A, pad_slot=self.n_slots)
         for row, (idx, req) in enumerate(group):
             mi[AI_SLOT, row] = idx
@@ -2738,9 +2833,8 @@ class ContinuousBatcher:
             Tt = self._tail_bucket(
                 max(len(r.prompt_ids) - plen for _, r in group)
             )
-            Tf = self._bucket(max(len(r.prompt_ids) for _, r in group))
             tail_tokens = np.zeros((A, Tt), np.int32)
-            full_tokens = np.zeros((A, Tf), np.int32)
+            full_tokens = np.zeros((A, T), np.int32)
             for row, (idx, req) in enumerate(group):
                 tail = req.prompt_ids[plen:]
                 tail_tokens[row, : len(tail)] = tail
@@ -2773,9 +2867,8 @@ class ContinuousBatcher:
                 max(len(r.prompt_ids) - plen for _, r in group)
             )
             assert plen + Tt <= self.max_seq_len  # _prefix_hit guarantees
-            Tf = self._bucket(max(len(r.prompt_ids) for _, r in group))
             tail_tokens = np.zeros((A, Tt), np.int32)
-            full_tokens = np.zeros((A, Tf), np.int32)
+            full_tokens = np.zeros((A, T), np.int32)
             for row, (idx, req) in enumerate(group):
                 tail = req.prompt_ids[plen:]
                 tail_tokens[row, : len(tail)] = tail
@@ -2786,7 +2879,6 @@ class ContinuousBatcher:
             prep.tail_tokens = tail_tokens
             prep.full_tokens = full_tokens
         else:
-            T = self._bucket(max(len(r.prompt_ids) for _, r in group))
             tokens = np.zeros((A, T), np.int32)
             for row, (idx, req) in enumerate(group):
                 ids = req.prompt_ids

@@ -271,3 +271,39 @@ async def test_mesh_scaling_ladder_stays_serviceable():
     assert all(r > 0 for r in rates.values())
     for key, rate in rates.items():
         assert rate > 0.1 * base, (key, rates)
+
+
+def test_row_floor_is_the_data_axis_and_a_lone_request_admits():
+    """The admission row ladder starts where the mesh's batch axes can
+    still split the rows (the sharded flash prefill takes the dense
+    fallback otherwise): 2 on a data axis of 2, where a lone request
+    is dispatched at two rows and decodes what one device decodes."""
+    from pilottai_tpu.parallel.mesh import MeshConfig, create_mesh
+    from pilottai_tpu.utils.metrics import global_metrics
+
+    cfg = get_model_config("llama-tiny")
+    params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+
+    def run(mesh):
+        b = ContinuousBatcher(
+            cfg, params, n_slots=4, max_seq_len=128,
+            cache_dtype=jnp.float32, chunk_size=4, prefix_cache=0,
+            use_pallas=False, mesh=mesh,
+        )
+        ladder = [b._row_bucket(n) for n in range(1, b.admit_batch + 1)]
+        b.start()
+        try:
+            run0 = global_metrics.get("engine.prefill_tokens_run")
+            out = b.submit(GenRequest(
+                prompt_ids=list(range(5, 105)), max_new_tokens=6, eos_id=-1,
+            )).result(timeout=240)
+            rows = (global_metrics.get("engine.prefill_tokens_run") - run0) / 128
+        finally:
+            b.stop()
+        return b.row_floor, ladder, rows, out
+
+    one = run(None)
+    two = run(create_mesh(MeshConfig(data=2), jax.devices()[:2]))
+    assert one[:3] == (1, [1, 2, 4, 4], 1)
+    assert two[:3] == (2, [2, 2, 4, 4], 2)
+    assert len(one[3]) == 6 and two[3] == one[3]

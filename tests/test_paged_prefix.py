@@ -210,3 +210,110 @@ async def test_paged_prefix_pressure_evicts_not_starves():
         assert all(isinstance(o, str) for o in outs)
     finally:
         await h.stop()
+
+
+# --------------------------------------------------------------------- #
+# Admission rows follow the group: every kind of admission, every rung
+# --------------------------------------------------------------------- #
+
+BASE = [(i % 90) + 5 for i in range(80)]     # five whole pages of 16
+# Tails that share no first token (no chain deeper than BASE forms) and
+# whose longest comes first, so every group's tail bucket is 16.
+TAILS = [
+    [100 + 7 * i + j for j in range(n)]
+    for i, n in enumerate((9, 3, 5, 7, 4, 6, 8, 2))
+]
+# kind: (batcher arguments, bucket the dispatch runs, rows by group size).
+# A prefix hit uses every rung of the ladder, and so does a full prefill at
+# the top bucket (``full_prefill_cap``, here max_seq_len); at a smaller
+# bucket a full prefill pads to ``admit_batch`` as it always did.
+EVERY_RUNG = {1: 1, 2: 2, 3: 4, 5: 8, 8: 8}
+KINDS = {
+    "full": (dict(prefix_cache=0, max_seq_len=128), 128, EVERY_RUNG),
+    "full_half_bucket": (dict(prefix_cache=0, max_seq_len=256), 128,
+                         {1: 8, 2: 8, 3: 8, 5: 8, 8: 8}),
+    "full_paged": (dict(prefix_cache=0, max_seq_len=128, paged=True,
+                        page_size=16), 128, EVERY_RUNG),
+    "prefix_paged": (dict(prefix_cache=8, max_seq_len=256, paged=True,
+                          page_size=16), 16, EVERY_RUNG),
+    "prefix": (dict(prefix_cache=8, max_seq_len=256), 16, EVERY_RUNG),
+}
+WAIT_S = 240.0
+
+
+def _ladder_batcher(kind):
+    import jax
+    import jax.numpy as jnp
+
+    from pilottai_tpu.engine.batcher import ContinuousBatcher
+    from pilottai_tpu.models.common import init_params
+    from pilottai_tpu.models.registry import get_model_config
+
+    cfg = get_model_config("llama-tiny")
+    params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    return ContinuousBatcher(
+        cfg, params, n_slots=8, cache_dtype=jnp.float32, chunk_size=4,
+        use_pallas=False, **KINDS[kind][0],
+    )
+
+
+def _ladder_run(kind, n, together):
+    """Outputs of the first ``n`` requests and the counters' movement
+    over their admission, after the shared prefix (where the kind has
+    one) was cached by a request of its own."""
+    from pilottai_tpu.engine.batcher import GenRequest
+
+    b = _ladder_batcher(kind)
+    b.start()
+    try:
+        if not kind.startswith("full"):
+            b.submit(GenRequest(prompt_ids=list(BASE), max_new_tokens=2)
+                     ).result(timeout=WAIT_S)
+        reqs = [
+            GenRequest(prompt_ids=BASE + tail, max_new_tokens=6, eos_id=-1)
+            for tail in TAILS[:n]
+        ]
+        before = dict(global_metrics.snapshot()["counters"])
+        if together:
+            b._submit_together(reqs)
+            outs = [r.future.result(timeout=WAIT_S) for r in reqs]
+        else:
+            outs = [b.submit(r).result(timeout=WAIT_S) for r in reqs]
+        after = dict(global_metrics.snapshot()["counters"])
+    finally:
+        b.stop()
+    return outs, {
+        k: after.get(k, 0.0) - before.get(k, 0.0)
+        for k in ("engine.admitted", "engine.prefix_hits",
+                  "engine.prefill_tokens_run", "engine.prefill_tokens_real",
+                  "engine.kvcache.prefill_tokens_saved")
+    }
+
+
+_ALONE = {}
+
+
+@pytest.mark.parametrize("n", [1, 2, 3, 5, 8])
+@pytest.mark.parametrize("kind", sorted(KINDS))
+def test_group_runs_its_rung_of_rows_and_the_same_tokens(kind, n):
+    """A group of ``n`` is dispatched at its rung of rows, not at
+    ``admit_batch``, and each request's greedy tokens are those it gets
+    when admitted alone (its own rung, its own tail bucket)."""
+    if kind not in _ALONE:
+        _ALONE[kind] = _ladder_run(kind, len(TAILS), together=False)[0]
+    outs, moved = _ladder_run(kind, n, together=True)
+    assert [len(o) for o in outs] == [6] * n
+    assert outs == _ALONE[kind][:n]
+    _, bucket, rungs = KINDS[kind]
+    rung = rungs[n]
+    hit = not kind.startswith("full")
+    assert moved["engine.admitted"] == n
+    assert moved["engine.prefix_hits"] == (n if hit else 0)
+    # one dispatch of rung x bucket: the tails' bucket where the prefix
+    # was mapped or copied, the prompts' where it was not
+    assert moved["engine.prefill_tokens_run"] == rung * bucket
+    saved = moved["engine.kvcache.prefill_tokens_saved"]
+    assert (saved >= n * (len(BASE) - 1)) == hit
+    assert moved["engine.prefill_tokens_real"] + saved == sum(
+        len(BASE) + len(t) for t in TAILS[:n]
+    )

@@ -261,7 +261,7 @@ def _counters():
 
 def test_fill_counters_on_a_hand_worked_schedule():
     b = _batcher()
-    lens, budgets = (23, 40), (7, 5)
+    lens, budgets = (23, 140), (7, 5)
     reqs = [
         GenRequest(prompt_ids=list(range(3, 3 + n)), max_new_tokens=m, eos_id=-1)
         for n, m in zip(lens, budgets)
@@ -282,13 +282,55 @@ def test_fill_counters_on_a_hand_worked_schedule():
     assert [len(o) for o in outs] == list(budgets)
     assert delta("engine.admitted") == 2
     assert delta("engine.prefill_tokens_real") == sum(lens)
-    # one group, padded to admit_batch rows x the longer prompt's bucket
-    assert delta("engine.prefill_tokens_run") == b.admit_batch * b._bucket(max(lens))
+    # one group of two: the two-row rung x the longer prompt's bucket
+    assert b._bucket(max(lens)) == 160 and b.admit_batch == 4
+    assert delta("engine.prefill_tokens_run") == 2 * 160
     first_tokens = len(reqs)
     assert delta("engine.decode_rows_active") == sum(budgets) - first_tokens
     assert delta("engine.decode_rows_active") == delta("engine.generated_tokens_device")
     assert delta("engine.decode_rows_run") == b.n_slots * delta("engine.decode_steps")
     assert delta("engine.decode_steps") == max(budgets) - 1
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_after_warmup_no_group_size_compiles_an_admission(paged):
+    """``warmup()`` sweeps every rung a prompt bucket's groups can run at,
+    so a group of any size up to ``admit_batch`` finds its full-prefill
+    program built: nothing is added to ``admit_group``'s cache while
+    serving."""
+    from pilottai_tpu.engine.batcher import admit_group
+
+    extra = dict(paged=True, page_size=16) if paged else {}
+    b = _batcher(**extra)
+    assert (b.n_slots, b.admit_batch, b.max_seq_len) == (4, 4, 160)
+    b.start()
+    try:
+        b.warmup()
+        built = admit_group._cache_size()
+        before = _counters()
+        buckets = sorted({b._bucket(n) for n in range(1, b.max_seq_len + 1)})
+        assert buckets == [64, 128, 160]
+        for bucket in buckets:
+            plen = min(bucket, b.max_seq_len - 8)
+            for n in range(1, b.admit_batch + 1):
+                reqs = [
+                    GenRequest(prompt_ids=list(range(9 + i, 9 + i + plen)),
+                               max_new_tokens=2)
+                    for i in range(n)
+                ]
+                b._submit_together(reqs)
+                for r in reqs:
+                    r.future.result(timeout=LIMIT_S)
+    finally:
+        b.stop()
+    # every group ran as one dispatch: at its rung at the top bucket, at
+    # admit_batch rows below it
+    assert [[b._row_bucket(n, bk) for n in (1, 2, 3, 4)] for bk in buckets] == [
+        [4, 4, 4, 4], [4, 4, 4, 4], [1, 2, 4, 4]]
+    run = _counters()["engine.prefill_tokens_run"] - before.get("engine.prefill_tokens_run", 0.0)
+    assert run == 16 * 64 + 16 * 128 + 11 * 160
+    # ... and none of them was new to the compiler
+    assert admit_group._cache_size() == built
 
 
 def _flight(edge, handler, wait, prefill, decode, tpot):
