@@ -158,52 +158,6 @@ class Tap:
             return [dict(self.seen[i], id=i) for i in ids if i in self.seen]
 
 
-def _register(cfg: Dict[str, Any], model: Any) -> None:
-    from pilottai_tpu.models.common import ModelConfig
-    from pilottai_tpu.models.registry import register_model
-
-    register_model(ModelConfig(
-        name=model.name, family="llama", vocab_size=model.vocab,
-        hidden_size=model.hidden, n_layers=model.layers, n_heads=model.heads,
-        n_kv_heads=model.kv_heads, head_dim=model.head_dim,
-        intermediate_size=model.ffn, max_seq_len=model.max_positions,
-        rope_theta=model.rope_theta, rms_eps=model.rms_eps,
-        tie_embeddings=False, n_experts=model.experts,
-        n_active_experts=model.experts_per_tok or 2,
-    ))
-
-
-def _program_params(model: Any, seed: int, int8: bool) -> Dict[str, Any]:
-    """The seed's weights in the tree the program serves: one jitted call
-    makes them, this only wraps the pairs in the program's ``QTensor``."""
-    import jax.numpy as jnp
-
-    from perfbench.weights import make_stack
-    from pilottai_tpu.models.quant import QTensor
-
-    stack = make_stack(model, seed)
-
-    def weight(pair):
-        q, s = pair
-        return QTensor(q=q, s=s) if int8 else q.astype(jnp.bfloat16) * s
-
-    lay, outer = stack["layers"], stack["outer"]
-    layers: Dict[str, Any] = {
-        "ln1": {"scale": lay["ln1"]}, "ln2": {"scale": lay["ln2"]},
-        "attn": {k: weight(lay[k]) for k in ("wq", "wk", "wv", "wo")},
-    }
-    mlp = {k: weight(lay[k]) for k in ("wg", "wu", "wd")}
-    if model.experts:
-        layers["moe"] = dict(mlp, router=lay["router"])
-    else:
-        layers["mlp"] = mlp
-    return {
-        "embed": outer["embed"], "layers": layers,
-        "final_norm": {"scale": outer["final_norm"]},
-        "lm_head": weight(outer["lm_head"]),
-    }
-
-
 class Server:
     def __init__(self, spec: Dict[str, Any]) -> None:
         self.spec = spec
@@ -221,6 +175,7 @@ class Server:
     # -- set-up ---------------------------------------------------------- #
 
     def prepare(self) -> None:
+        from perfbench import archs
         from perfbench.weights import load_config, model_from_config
 
         if self.platform == "cpu":
@@ -237,18 +192,20 @@ class Server:
             raise SystemExit(NO_CHIP_EXIT)
         self.cfg = load_config(self.spec["config"])
         self.model = model_from_config(self.cfg)
-        _register(self.cfg, self.model)
+        arch = archs.of(self.model)
         self.compiles.install()
 
         from pilottai_tpu.engine import native
+        from pilottai_tpu.models.registry import register_model
         from pilottai_tpu.obs import global_flight
 
+        register_model(arch.program_config(self.cfg, self.model))
         argv = list(self.cfg["serve"]["argv"])
         int8 = "int8" in _flag(argv, "--quantize", "")
         model, seed = self.model, self.seed
 
         def init_from_seed(cfg: Any, key: Any, dtype: Any = None, quantize: bool = False):
-            return _program_params(model, seed, int8)
+            return arch.program_params(model, seed, int8)
 
         native.init_params = init_from_seed
         start = native.NativeEngine._start_blocking
