@@ -96,3 +96,22 @@ def test_a_broken_timed_path_is_not_correct(capsys, fault, check):
     assert c["value"] > c["limit"]
     if fault == "cut_short":
         assert line["failed"] == line["attempted"] > 0
+
+
+WINDOW_FORGOTTEN = "PERFBENCH_TINY_WINDOW_FORGET"   # data/archs/tiny_window.py's own switch
+
+
+@pytest.mark.parametrize("forget", [False, True])
+def test_an_architecture_brought_as_new_files_runs_the_whole_command(capsys, monkeypatch, forget):
+    """``data/tiny-window.json`` names ``data/archs/tiny_window.py``: a sliding
+    window on three layers of four (16 keys; the prompts are 120-200 tokens)
+    and post-norms, neither of which ``archs/mistral.py`` can say. The run is
+    correct against the module's own reference, and not correct against the
+    same reference made to forget the window."""
+    if forget:
+        monkeypatch.setenv(WINDOW_FORGOTTEN, "1")
+    code, line, _ = drive(capsys, "tiny-closed.json", 15, config="tiny-window.json")
+    assert code == 0 and line["failed"] == 0 and line["attempted"] > 0
+    assert line["correct"] is not forget
+    gap = line["checks"]["gap_max"]
+    assert (gap["value"] > gap["limit"]) is forget
