@@ -82,32 +82,39 @@ def found() -> List[str]:
     return sorted(p.stem for p in HERE.glob("*.py") if not p.stem.startswith("_"))
 
 
+def _from_path(path: Path) -> ModuleType:
+    """A module outside this directory, imported once under a name made
+    from its path."""
+    name = "perfbench_arch_" + "_".join(path.with_suffix("").parts[1:])
+    if name in sys.modules:
+        return sys.modules[name]
+    if not path.is_file():
+        raise ValueError(f"no architecture module at {path}")
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    sys.modules[name] = module      # dataclasses look their module up here
+    try:
+        spec.loader.exec_module(module)
+    except BaseException:
+        del sys.modules[name]
+        raise
+    return module
+
+
 def load(arch: Any) -> ModuleType:
     """The module a configuration's ``arch`` names, checked against the list
-    above. A path is imported once under a name made from it."""
+    above."""
+    names = found()
     if not isinstance(arch, str) or not arch:
         raise ValueError(
-            f"a configuration names its architecture with the key \"arch\": one of {found()} "
+            f"a configuration names its architecture with the key \"arch\": one of {names} "
             "(perfbench/archs/<arch>.py) or a path that ends in .py")
     if arch.endswith(".py"):
-        path = Path(arch).resolve()
-        name = "perfbench_arch_" + "_".join(path.with_suffix("").parts[1:])
-        module = sys.modules.get(name)
-        if module is None:
-            if not path.is_file():
-                raise ValueError(f"no architecture module at {path}")
-            spec = importlib.util.spec_from_file_location(name, path)
-            module = importlib.util.module_from_spec(spec)
-            sys.modules[name] = module      # dataclasses look their module up here
-            try:
-                spec.loader.exec_module(module)
-            except BaseException:
-                del sys.modules[name]
-                raise
-    elif arch in found():
+        module = _from_path(Path(arch).resolve())
+    elif arch in names:
         module = importlib.import_module(f"perfbench.archs.{arch}")
     else:
-        raise ValueError(f"unknown arch {arch!r}: perfbench/archs/ has {found()}")
+        raise ValueError(f"unknown arch {arch!r}: perfbench/archs/ has {names}")
     lacking = [n for n in REQUIRED if not hasattr(module, n)]
     if lacking:
         raise AttributeError(
