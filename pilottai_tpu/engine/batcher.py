@@ -79,6 +79,7 @@ from pilottai_tpu.engine.decode import (
     AF_TOPP,
     DecodeState,
     _paged_kernel_for,
+    _refuse_recurrent,
     admit_group,
     admit_group_prefix,
     admit_group_prefix_paged,
@@ -95,7 +96,7 @@ from pilottai_tpu.engine.prefix_cache import PrefixStore
 from pilottai_tpu.engine.sampling import SamplingState
 from pilottai_tpu.models.common import ModelConfig
 from pilottai_tpu.models.quant import weight_stream_bytes
-from pilottai_tpu.ops.kvcache import KVCache, free_slots
+from pilottai_tpu.ops.kvcache import KVCache, StatePool, free_slots
 from pilottai_tpu.ops.paged import PageAllocator, PagedKVCache
 from pilottai_tpu.ops.pallas.decode_attention import decode_shapes_ok
 from pilottai_tpu.ops.pallas.paged_attention import paged_sharding_ok
@@ -584,6 +585,15 @@ class ContinuousBatcher:
         # weight pass (engine/decode.py:decode_chunk_spec) — both caches
         # (the paged chunk reads its prefix through the block table).
         self.speculate = speculate if speculate >= 2 else 0
+        # A stack of unlike layers (cfg.layer_kinds) runs what was built
+        # for it and refuses the rest by name; nothing may run and drop
+        # the recurrent state.
+        _refuse_recurrent(cfg, "speculative decoding", bool(self.speculate))
+        if cfg.layer_kinds and (weight_quant != "none" or kv_quantize):
+            raise NotImplementedError(
+                f"{cfg.name} ({cfg.family}) is served in bfloat16 only: weight "
+                f"and KV quantization are not built for its layers"
+            )
         # Warmup sweeps must compile the FULL-prefill buckets — gate the
         # paged index during warmup so warmup prompts (which share
         # prefixes by construction) don't short-circuit into the
@@ -696,6 +706,13 @@ class ContinuousBatcher:
         # granularity is per page rather than per whole prompt.
         self.prefix_store = None
         self.page_index = None
+        # A shared prefix is only KV; a recurrent layer would need a
+        # snapshot of its state at the boundary, which nothing takes. So
+        # a model with recurrent state builds no store and no index, and
+        # every admission that would have looked is counted.
+        self._prefix_bypass = bool(cfg.recurrent and prefix_cache > 0)
+        if self._prefix_bypass:
+            prefix_cache = 0
         if prefix_cache > 0:
             if paged:
                 self.page_index = PagePrefixIndex(
@@ -1469,6 +1486,7 @@ class ContinuousBatcher:
         under the slot lock so no spill/restore interleaves. None when
         the KV cache tier is off or the session is unknown — callers
         treat that as 'nothing to move' (the target re-prefills)."""
+        _refuse_recurrent(self.cfg, "export_session_kv")
         if self.kvcache is None or self.kvcache.host is None:
             return None
         with self._lock:
@@ -1479,6 +1497,7 @@ class ContinuousBatcher:
         entries in this engine's host tier so the session's next turn
         restores here instead of re-prefilling. Returns the accepted
         entry/token counts (budget pressure may reject some)."""
+        _refuse_recurrent(self.cfg, "import_session_kv")
         if self.kvcache is None or self.kvcache.host is None or not export:
             return {"accepted": 0, "tokens": 0, "rejected": 0}
         with self._lock:
@@ -1495,6 +1514,7 @@ class ContinuousBatcher:
         slot lock so the export overlaps only between device steps,
         never mid-gather. None when the cache tier is off or holds
         nothing for this prompt — the caller serves colocated."""
+        _refuse_recurrent(self.cfg, "export_request_kv")
         if self.kvcache is None:
             return None
         with self._lock:
@@ -1510,6 +1530,7 @@ class ContinuousBatcher:
         integrity gate as session import: a corrupt frame rejects,
         counts ``engine.kvcache.integrity_failures``, and the request
         falls back to colocated serving."""
+        _refuse_recurrent(self.cfg, "import_request_kv")
         if self.kvcache is None or self.kvcache.host is None or not export:
             return {"accepted": 0, "tokens": 0, "rejected": 0}
         # Deliberately NOT under the batcher lock: the import only
@@ -1694,6 +1715,9 @@ class ContinuousBatcher:
         H2D staged here on the prep thread; the pool write for paged
         chains runs on the device thread via _apply_restores) instead
         of re-prefilling. Called under the slot lock."""
+        if self._prefix_bypass and not self._warming and not req.kv_counted:
+            req.kv_counted = True
+            global_metrics.inc("engine.prefix_bypassed_recurrent")
         if self.kvcache is None or self._warming:
             # Warmup gate: the sweep's ascending same-start prompts
             # would otherwise hit earlier rungs' entries and admit via
@@ -2712,6 +2736,10 @@ class ContinuousBatcher:
                         jnp.asarray(seg_tokens),
                         jnp.asarray([seg], np.int32),
                         jnp.asarray(self.alloc.table[idx][None]),
+                        slot=(
+                            jnp.asarray([idx], np.int32)
+                            if self.cfg.layer_kinds else None
+                        ),
                     )
                 global_metrics.inc("engine.prefill_segments")
                 if not self._warming:
@@ -3081,6 +3109,8 @@ class ContinuousBatcher:
                 ([(idx, self._gen[idx]) for idx, _ in group], first_copy)
             )
             slots_active = sum(s is not None for s in self._slots)
+        if self.cfg.recurrent:
+            global_metrics.set_gauge("engine.state_slots_live", float(slots_active))
         for _, req in group:
             # Queue wait = submit → slot granted: the flight's admitted
             # mark is THE source of request.queue_wait_s (one histogram,
@@ -3540,7 +3570,7 @@ class ContinuousBatcher:
         use_pallas_now = self.use_pallas
         if self.paged and self.use_pallas:
             gather_bytes = (
-                2 * self.cfg.n_layers * self.n_slots * self.cfg.n_kv_heads
+                2 * self.cfg.n_kv_layers * self.n_slots * self.cfg.n_kv_heads
                 * prefix_bound * self.cfg.head_dim
                 * jnp.dtype(self.cfg.dtype).itemsize
             )
@@ -3647,7 +3677,13 @@ class ContinuousBatcher:
         # reader folds from this already-in-flight copy one pipeline
         # cycle later (a wait on a landed transfer, not a fresh blocking
         # device→host sync — and never a jax.device_get).
-        copies = _HostCopy((toks, valid))
+        # The expert layers' running counts ride the cache, which the next
+        # dispatch donates: a copy of the two numbers is what goes home.
+        routed = (
+            (jnp.copy(self.cache.state.routed),)
+            if self.cache.state is not None else ()
+        )
+        copies = _HostCopy((toks, valid) + routed)
         with self._lock:
             self._inflight += 1
         # engine.decode_steps is counted at fold time (_process_chunk)
@@ -3673,8 +3709,17 @@ class ContinuousBatcher:
             groups = list(self._first_reads)
             self._first_reads.clear()
         with global_metrics.timer("engine.chunk_read_latency"):
-            toks_h, valid_h = copies.wait()
+            toks_h, valid_h, *routed_h = copies.wait()
             first_hosts = [copy.wait()[0] for _, copy in groups]
+        if routed_h:
+            # Token-expert pairs routed, and those that landed on experts
+            # held here, since the last fold (admissions' included); the
+            # device's counts wrap at 2**32, so the difference does too.
+            delta = routed_h[0] - self._routed_seen
+            self._routed_seen = routed_h[0]
+            if not self._warming:
+                global_metrics.inc("engine.moe_assignments", int(delta[0]))
+                global_metrics.inc("engine.moe_assignments_held", int(delta[1]))
         # The device's result is on the host: what follows is this
         # thread's own work.
         with host_span("reader.process_chunk", blocks=n_blocks):
@@ -4031,11 +4076,16 @@ class ContinuousBatcher:
             # the failure arm's viable() check and here — the caller's
             # retry path handles it like any failed rebuild.
             self._replan_mesh()
+        # KV for the layers that keep KV; beside it, for a stack of unlike
+        # layers, the per-slot pool of the others' state.
+        state = StatePool.create(self.cfg, self.n_slots, self.cache_dtype)
+        self._routed_seen = np.zeros((2,), np.uint32)
         if self.paged:
             cache = PagedKVCache.create(
-                self.cfg.n_layers, self.n_slots, self.num_pages,
+                self.cfg.n_kv_layers, self.n_slots, self.num_pages,
                 self.page_size, self.cfg.n_kv_heads, self.cfg.head_dim,
                 dtype=self.cache_dtype, quantized=self.kv_quantize,
+                state=state,
             )
             alloc = PageAllocator(
                 self.num_pages, self.page_size, self.n_slots,
@@ -4043,9 +4093,10 @@ class ContinuousBatcher:
             )
         else:
             cache = KVCache.create(
-                self.cfg.n_layers, self.n_slots, self.max_seq_len,
+                self.cfg.n_kv_layers, self.n_slots, self.max_seq_len,
                 self.cfg.n_kv_heads, self.cfg.head_dim,
                 dtype=self.cache_dtype, quantized=self.kv_quantize,
+                state=state,
             )
             alloc = None
         # Serving-mesh layout AT CREATION (parallel/sharding.py): paged

@@ -36,6 +36,7 @@ from pilottai_tpu.engine.sampling import (
     split_step_keys,
 )
 from pilottai_tpu.models.common import ModelConfig, rms_norm, rope_tables
+from pilottai_tpu.models.hybrid import forward_prefill_hybrid, walk_layers
 from pilottai_tpu.models.qmatmul import qmatmul
 from pilottai_tpu.models.quant import Q4Tensor, QTensor
 from pilottai_tpu.models.transformer import (
@@ -68,6 +69,17 @@ from pilottai_tpu.ops.pallas.paged_attention import (
 )
 
 NEG_INF = -2.0**30
+
+
+def _refuse_recurrent(cfg: ModelConfig, what: str, asked: bool = True) -> None:
+    """A model with recurrent state (``cfg.recurrent``) may not run a path
+    that would carry its KV and drop its state: refuse it by name."""
+    if asked and cfg.recurrent:
+        raise NotImplementedError(
+            f"{cfg.name} ({cfg.family}) keeps Mamba-2 state beside its KV "
+            f"cache, and {what} would carry the KV and drop the state: not "
+            f"built for a model with recurrent state"
+        )
 
 
 def _paged_kernel_for(kv_mesh):
@@ -515,7 +527,7 @@ def decode_chunk(
                 _bounded_panels(
                     cache, l, lambda a: gather_pages(a, table, n_blocks),
                 )
-                for l in range(cfg.n_layers)
+                for l in range(cfg.n_kv_layers)
             )
     else:
         S = cache.max_len
@@ -528,7 +540,7 @@ def decode_chunk(
             _bounded_panels(
                 cache, l, lambda a: jax.lax.slice_in_dim(a, 0, Sb, axis=2),
             )
-            for l in range(cfg.n_layers)
+            for l in range(cfg.n_kv_layers)
         )
     start = cache.lengths                    # [B] frozen during the chunk
     windows = cfg.window_sizes()
@@ -542,28 +554,29 @@ def decode_chunk(
     )
     rings = tuple(
         (jnp.zeros(batch_shape, cache_dtype), jnp.zeros(batch_shape, cache_dtype))
-        for _ in range(cfg.n_layers)
+        for _ in range(cfg.n_kv_layers)
     )
     prefix_last = start - 1                  # max valid prefix key index
 
     def step(carry):
-        i, tokens, done, budget, offset, sampling, rings, out_t, out_v = carry
+        (
+            i, tokens, done, budget, offset, sampling, rings, out_t, out_v,
+            extra,
+        ) = carry
         active = ~done
         pos = start + offset                 # current token's position
         x = _embed(cfg, params, tokens[:, None])          # [B, 1, E]
         sin, cos = rope_tables(pos[:, None], cfg.head_dim, cfg.rope_theta)
 
         new_rings = []
-        for l in range(cfg.n_layers):
-            lp = jax.tree.map(lambda a: a[l], params["layers"])
-            window = int(windows[l])
+
+        def attend(l, p, h, window=0):
+            """Layer ``l`` of the cache: this step's K/V into its ring,
+            then the token's attention over the prefix and the ring.
+            Returns ``(attn [B, 1, heads, H], (ring k, ring v))``."""
             layer_k, layer_v, layer_sc = prefix_panels[l]
             rk, rv = rings[l]
-            p = lp["attn"]
-
-            h = rms_norm(x, lp["ln1"]["scale"], cfg.rms_eps, cfg.rms_offset)
             q, k, v = _qkv(cfg, p, h, sin, cos)  # [B, 1, heads, H]
-
             rk = jax.lax.dynamic_update_slice(
                 rk, k[:, 0][:, :, None].astype(rk.dtype), (0, 0, i, 0)
             )
@@ -613,11 +626,26 @@ def decode_chunk(
                 )
                 attn = _combine_stats(acc_p, m_p, l_p, acc_c, m_c, l_c)
 
-            x = _layer_tail(
-                cfg, lp, x,
-                attn.astype(x.dtype).reshape(B, 1, cfg.n_heads, cfg.head_dim),
+            return (
+                attn.astype(h.dtype).reshape(B, 1, cfg.n_heads, cfg.head_dim),
+                (rk, rv),
             )
-            new_rings.append((rk, rv))
+
+        if cfg.layer_kinds:
+            # One mixer a layer, in the published order; the state-space
+            # layers move the state of the rows that are active.
+            conv, ssm, routed = extra
+            x, new_rings, conv, ssm, n = walk_layers(
+                cfg, params, x, attend, conv, ssm, active[:, None],
+            )
+            extra = (conv, ssm, routed + n)
+        else:
+            for l in range(cfg.n_layers):
+                lp = jax.tree.map(lambda a: a[l], params["layers"])
+                h = rms_norm(x, lp["ln1"]["scale"], cfg.rms_eps, cfg.rms_offset)
+                attn, ring = attend(l, lp["attn"], h, int(windows[l]))
+                x = _layer_tail(cfg, lp, x, attn)
+                new_rings.append(ring)
 
         h = rms_norm(x, params["final_norm"]["scale"], cfg.rms_eps, cfg.rms_offset)
         if fused_epilogue:
@@ -645,25 +673,35 @@ def decode_chunk(
         out_v = jax.lax.dynamic_update_slice(out_v, active[None], (i, 0))
         return (
             i + 1, new_tokens, new_done, new_budget, new_offset, sampling,
-            tuple(new_rings), out_t, out_v,
+            tuple(new_rings), out_t, out_v, extra,
         )
 
     offset0 = jnp.zeros((B,), jnp.int32)
+    # The state pool of a stack of unlike layers rides the loop and is
+    # updated in place; () for a model whose layers all keep KV.
+    state = cache.state
+    extra0 = () if state is None else (state.conv, state.ssm, state.routed)
     carry0 = (
         jnp.int32(0), dstate.tokens, dstate.done, dstate.budget, offset0,
         sampling, rings,
         jnp.zeros((n_steps, B), jnp.int32), jnp.zeros((n_steps, B), bool),
+        extra0,
     )
     # while_loop with all-done early exit (see decode_chunk_spec): each
     # step streams the full weight set, so steps past the last active
     # slot are pure waste — the dispatch now pays only for steps used.
     (
         _, tokens, done, budget, offset, sampling, rings, out_toks, out_valid,
+        extra,
     ) = jax.lax.while_loop(
         lambda c: (c[0] < n_steps) & ~jnp.all(c[2]),
         step,
         carry0,
     )
+    if state is not None:
+        cache = cache._replace(state=state._replace(
+            conv=extra[0], ssm=extra[1], routed=extra[2],
+        ))
 
     if paged:
         cache = write_chunk_rows_paged(
@@ -1082,6 +1120,7 @@ def decode_chunk_spec(
     kernel streams each block's D queries against the slot's pages
     (``q_blocks``), or the XLA fallback materializes bounded dense
     panels once per chunk (pool contents are frozen during the scan)."""
+    _refuse_recurrent(cfg, "speculative decoding")
     from pilottai_tpu.engine.sampling import _advance_json, fused_verify_rows
 
     B = dstate.tokens.shape[0]
@@ -1648,6 +1687,7 @@ def admit_group_prefix(
     (~33 TFLOP, the dominant share of the agent-step wave measured on
     v5e) collapses to a single position."""
     A, Tt = tail_tokens.shape
+    _refuse_recurrent(cfg, "a prefix hit (the dense prefix store)")
     (
         slots, temps, topks, topps, seeds, eos, jsonm, budgets, tail_lens,
         schema_ids,
@@ -1790,10 +1830,20 @@ def admit_group_prefix_paged(
     cache_dtype = (
         cfg.dtype if cache.scales is not None else cache.layers[0][0].dtype
     )
-    logits, ks, vs = _chain_tail_prefill(
-        params, cfg, cache, prefix_pages, prefix_len, tail_tokens,
-        tail_lens, cache_dtype,
-    )
+    if cfg.layer_kinds:
+        # Only ever a slot's OWN chain (the last segment of a prompt
+        # admitted in segments): a shared prefix is KV alone, and the
+        # batcher takes no prefix hit for a model with recurrent state.
+        _refuse_recurrent(cfg, "speculative decoding", history is not None)
+        logits, ks, vs, cache = _tail_prefill_kinds(
+            params, cfg, cache, prefix_pages, prefix_len, tail_tokens,
+            tail_lens, cache_dtype, slots,
+        )
+    else:
+        logits, ks, vs = _chain_tail_prefill(
+            params, cfg, cache, prefix_pages, prefix_len, tail_tokens,
+            tail_lens, cache_dtype,
+        )
 
     # Tail install: position t of the tail lives at absolute position
     # prefix_len + t — write through the slot's own table with that
@@ -1837,6 +1887,8 @@ def extend_prompt_paged(
     seg_tokens: jax.Array,    # [1, Ts] right-padded prompt segment
     seg_lens: jax.Array,      # [1] true segment length
     page_rows: jax.Array,     # [1, max_pages] the slot's block table
+    slot: Optional[jax.Array] = None,  # [1] int32 — the slot, for a model
+                              # whose state pool the segment goes on from
 ):
     """One chunked-prefill segment of a long prompt (VERDICT r5 #6):
     prefill ``seg_tokens`` attending to the KV already written for this
@@ -1849,15 +1901,80 @@ def extend_prompt_paged(
     cache_dtype = (
         cfg.dtype if cache.scales is not None else cache.layers[0][0].dtype
     )
-    _logits, ks, vs = _chain_tail_prefill(
-        params, cfg, cache, prefix_pages, prefix_len, seg_tokens, seg_lens,
-        cache_dtype,
-    )
+    if cfg.layer_kinds:
+        assert slot is not None, "a segment of such a model names its slot"
+        _logits, ks, vs, cache = _tail_prefill_kinds(
+            params, cfg, cache, prefix_pages, prefix_len, seg_tokens,
+            seg_lens, cache_dtype, slot,
+        )
+    else:
+        _logits, ks, vs = _chain_tail_prefill(
+            params, cfg, cache, prefix_pages, prefix_len, seg_tokens,
+            seg_lens, cache_dtype,
+        )
     ks_w = ks.transpose(0, 1, 3, 2, 4)  # [L, 1, Ts, K, H]
     vs_w = vs.transpose(0, 1, 3, 2, 4)
     return write_prompts_paged(
         cache, page_rows, ks_w, vs_w, seg_lens, pos_offset=prefix_len
     )
+
+
+def _chain_gatherer(cfg, cache, prefix_pages):
+    """``l -> (pk [K, Pb, H], pv)``: layer ``l`` of the cache's chain pages
+    as prefix panels in compute dtype."""
+    K = cache.n_kv_heads
+    Pb = prefix_pages.shape[0] * cache.page_size
+
+    def _chain_gather(a):
+        return a[:, prefix_pages].reshape((K, Pb) + a.shape[3:])
+
+    def gather_layer(l):
+        k_, v_, sc = _bounded_panels(cache, l, _chain_gather)
+        return _dequant_pair(k_, v_, sc, cfg.dtype)
+
+    return gather_layer
+
+
+def _tail_prefill_kinds(
+    params, cfg, cache, prefix_pages, prefix_len, tail_tokens, tail_lens,
+    cache_dtype, rows,
+):
+    """The tail prefill of a stack of unlike layers (``cfg.layer_kinds``)
+    against the slot's OWN page chain, which is how a prompt admitted in
+    segments goes on: attention reads the chain as ``_tail_prefill_lazy``
+    does, and the state-space layers go on from the conv and state-space
+    state the pool holds for ``rows`` (zeros when nothing came before).
+    Returns ``(last_logits, ks, vs, cache)`` with the pool's rows
+    rewritten."""
+    A, Tt = tail_tokens.shape
+    K, H = cfg.n_kv_heads, cfg.head_dim
+    G = cfg.n_heads // K
+    qscale = cfg.query_scale if cfg.query_scale is not None else H**-0.5
+    gather_layer = _chain_gatherer(cfg, cache, prefix_pages)
+    real = jnp.arange(Tt)[None, :] < tail_lens[:, None]
+
+    def attend(l, p, h):
+        pk, pv = gather_layer(l)
+        q, k, v = _qkv(cfg, p, h, None, None)
+        qg = q.transpose(0, 2, 1, 3).reshape(A, K, G, Tt, H)
+        blk_k = k.transpose(0, 2, 1, 3).astype(cache_dtype)
+        blk_v = v.transpose(0, 2, 1, 3).astype(cache_dtype)
+        attn = _tail_prefix_attn(
+            qg, pk, pv, blk_k, blk_v, prefix_len, tail_lens, qscale,
+            cfg.attn_softcap, 0,
+        )
+        return attn.astype(h.dtype).reshape(A, Tt, cfg.n_heads, H), (blk_k, blk_v)
+
+    conv0, ssm0 = cache.state.rows(rows, prefix_len == 0)
+    x = _embed(cfg, params, tail_tokens)
+    x, kv, conv, ssm, routed = walk_layers(
+        cfg, params, x, attend, conv0, ssm0, real, lens=tail_lens
+    )
+    x = _last_rows(x, tail_lens)
+    x = rms_norm(x, params["final_norm"]["scale"], cfg.rms_eps, cfg.rms_offset)
+    logits = _unembed(cfg, params, x)
+    cache = cache._replace(state=cache.state.write(rows, conv, ssm, routed))
+    return logits, jnp.stack([k for k, _ in kv]), jnp.stack([v for _, v in kv]), cache
 
 
 def _chain_tail_prefill(
@@ -1873,17 +1990,9 @@ def _chain_tail_prefill(
     stacked, a measured OOM."""
     import os as _os
 
-    P = cache.page_size
     K = cache.n_kv_heads
-    Pb = prefix_pages.shape[0] * P
-
-    def _chain_gather(a):
-        return a[:, prefix_pages].reshape((K, Pb) + a.shape[3:])
-
-    def gather_layer(l):
-        k_, v_, sc = _bounded_panels(cache, l, _chain_gather)
-        return _dequant_pair(k_, v_, sc, cfg.dtype)
-
+    Pb = prefix_pages.shape[0] * cache.page_size
+    gather_layer = _chain_gatherer(cfg, cache, prefix_pages)
     budget = int(_os.environ.get("PILOTTAI_GATHER_BUDGET", 5 * 1024**3))
     stacked_bytes = (
         2 * cfg.n_layers * K * Pb * cache.head_dim
@@ -1995,12 +2104,25 @@ def admit_group(
         slots, temps, topks, topps, seeds, eos, jsonm, budgets, lens,
         schema_ids,
     ) = _unpack_admit_meta(meta_i32, meta_f32, schema_tables)
-    positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (A, T))
-    logits, ks, vs = forward_prefill(
-        params, cfg, tokens, positions, lens,
-        use_flash=use_flash, flash_mesh=flash_mesh,
-        logit_positions=jnp.maximum(lens - 1, 0),
-    )
+    if cfg.layer_kinds:
+        # A stack of unlike layers: KV for its attention layers only, and
+        # each row's conv and state-space state after its true length,
+        # written over whatever the slot held.
+        _refuse_recurrent(cfg, "speculative decoding", history is not None)
+        logits, ks, vs, conv, ssm, routed = forward_prefill_hybrid(
+            params, cfg, tokens, lens, use_flash=use_flash,
+            logit_positions=jnp.maximum(lens - 1, 0),
+        )
+        cache = cache._replace(
+            state=cache.state.write(slots, conv, ssm, routed)
+        )
+    else:
+        positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (A, T))
+        logits, ks, vs = forward_prefill(
+            params, cfg, tokens, positions, lens,
+            use_flash=use_flash, flash_mesh=flash_mesh,
+            logit_positions=jnp.maximum(lens - 1, 0),
+        )
     if isinstance(cache, PagedKVCache):
         assert page_rows is not None, "paged admission needs page rows"
         cache = write_prompts_paged(cache, page_rows, ks, vs, lens)

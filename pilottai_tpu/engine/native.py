@@ -125,6 +125,13 @@ class NativeEngine(LLMBackend):
                     f"{devices[0].platform!r} ({devices[0].device_kind}); "
                     f'use provider="cpu" to serve from the host'
                 )
+        if self.model_cfg.layer_kinds and not self.config.mesh_shape:
+            # A stack of Mamba-2 and latent-expert layers is one chip's
+            # share of its deployment (ModelConfig.experts_held): with no
+            # mesh asked for it takes the first device, however many the
+            # host has. An explicit mesh is refused where the parameters
+            # are laid out (models/common.py:param_logical_axes).
+            devices = devices[:1]
         mesh_cfg = (
             MeshConfig.from_dict(self.config.mesh_shape)
             if self.config.mesh_shape

@@ -4,6 +4,12 @@ One ``ModelConfig`` covers the Llama and Gemma families; family-specific
 behaviors (activation, embed scaling, RMSNorm offset, logit soft-caps,
 alternating sliding windows, post-norms) are explicit fields rather than
 subclasses, so the single ``transformer.py`` forward stays scan-friendly.
+
+A stack whose layers are not all alike (family ``nemotron_h``) names each
+layer's kind in ``layer_kinds``: one mixer behind one norm, ``M`` (Mamba-2,
+``models/ssm.py``), ``E`` (latent experts, ``models/moe.py``) or ``*``
+(attention). Its parameters are one dict per layer, in the published order,
+and ``models/hybrid.py`` walks them; the llama scan is not involved.
 """
 
 from __future__ import annotations
@@ -20,7 +26,7 @@ import numpy as np
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str = "tiny-test"
-    family: str = "llama"  # "llama" | "gemma" | "gemma2"
+    family: str = "llama"  # "llama" | "gemma" | "gemma2" | "nemotron_h"
     vocab_size: int = 512
     hidden_size: int = 256
     n_layers: int = 4
@@ -35,6 +41,11 @@ class ModelConfig:
 
     # Family behaviors
     act: str = "silu"              # "silu" (llama) | "gelu_tanh" (gemma)
+                                   # | "relu2" (squared ReLU, nemotron_h)
+    mlp_gated: bool = True         # False: down(act(up(x))), no gate
+    rope: bool = True              # False: attention applies no rotary
+                                   # embedding (nemotron_h: the state-space
+                                   # layers carry position)
     scale_embed: bool = False      # gemma: x *= sqrt(hidden)
     rms_offset: bool = False       # gemma: scale = (1 + w)
     post_norms: bool = False       # gemma2: post-attn / post-mlp norms
@@ -48,6 +59,28 @@ class ModelConfig:
     # logical axis (mesh 'model' by default) — expert parallelism.
     n_experts: int = 0
     n_active_experts: int = 2      # top-k routing
+    # Latent experts (``moe_router == "sigmoid"``, models/moe.py's second
+    # path): the router scores all ``n_experts``; this chip holds the
+    # experts ``experts_held = (lo, hi)`` (None = all) and computes only
+    # assignments that land on them, in a ``moe_latent``-wide space.
+    moe_router: str = "softmax"    # "softmax" (Mixtral) | "sigmoid"
+    experts_held: Optional[Tuple[int, int]] = None
+    moe_intermediate: int = 0      # one routed expert's width
+    moe_latent: int = 0            # width the routed experts compute in
+    moe_shared_intermediate: int = 0  # the shared expert's width
+    moe_scale: float = 1.0         # routed_scaling_factor
+
+    # Per-layer kinds of a stack whose layers are not all alike: "M"
+    # Mamba-2, "E" experts, "*" attention; () = every layer attention
+    # then MLP (the llama scan). len(layer_kinds) == n_layers.
+    layer_kinds: Tuple[str, ...] = ()
+    ssm_heads: int = 0             # Mamba-2 heads
+    ssm_head_dim: int = 0
+    ssm_groups: int = 0            # B/C groups (head h reads group
+                                   # h // (heads / groups))
+    ssm_state: int = 0             # state size N
+    ssm_conv: int = 4              # depthwise conv kernel
+    ssm_chunk: int = 128           # chunk of the blocked scan
 
     dtype: Any = jnp.bfloat16
 
@@ -58,6 +91,57 @@ class ModelConfig:
     @property
     def kv_dim(self) -> int:
         return self.n_kv_heads * self.head_dim
+
+    @property
+    def recurrent(self) -> bool:
+        """Whether some layer keeps state that is not KV (a prefix of
+        such a model cannot be shared by mapping pages alone)."""
+        return "M" in self.layer_kinds
+
+    @property
+    def n_kv_layers(self) -> int:
+        """Layers that keep KV: the attention layers only."""
+        if self.layer_kinds:
+            return sum(k == "*" for k in self.layer_kinds)
+        return self.n_layers
+
+    @property
+    def n_held(self) -> int:
+        lo, hi = self.experts_held or (0, self.n_experts)
+        return hi - lo
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels through the convolution: x, B and C."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    def kind_params(self, kind: str, held_only: bool = True) -> int:
+        """Parameters of one layer of a ``layer_kinds`` stack, its norm
+        included; ``held_only=False`` counts every routed expert."""
+        E = self.hidden_size
+        if kind == "*":
+            return 2 * E * self.q_dim + 2 * E * self.kv_dim + E
+        if kind == "M":
+            I, H = self.ssm_inner, self.ssm_heads
+            C = self.ssm_conv_dim
+            return (
+                E * (I + C + H) + (self.ssm_conv + 1) * C + 3 * H + I
+                + I * E + E
+            )
+        if kind == "E":
+            X = self.n_held if held_only else self.n_experts
+            one = 2 * self.moe_latent * self.moe_intermediate
+            return (
+                E * self.n_experts + self.n_experts       # router, bias
+                + 2 * E * self.moe_latent                  # in / out of latent
+                + X * one
+                + 2 * E * self.moe_shared_intermediate + E
+            )
+        raise ValueError(f"unknown layer kind {kind!r}")
 
     def window_sizes(self) -> np.ndarray:
         """Per-layer sliding-window sizes; 0 = global attention."""
@@ -72,6 +156,13 @@ class ModelConfig:
 
     def param_count(self) -> int:
         E, F, V, L = self.hidden_size, self.intermediate_size, self.vocab_size, self.n_layers
+        if self.layer_kinds:
+            # What this chip holds: its share of the routed experts.
+            head = 0 if self.tie_embeddings else E * V
+            return (
+                V * E + sum(self.kind_params(k) for k in self.layer_kinds)
+                + E + head
+            )
         mlp = 2 * E * F + F * E
         if self.n_experts > 0:
             mlp = self.n_experts * mlp + E * self.n_experts  # experts + router
@@ -88,6 +179,15 @@ class ModelConfig:
         ``param_count`` for dense models; for MoE, only the router plus
         the top-k routed experts' MLPs count — the inactive experts'
         weights never stream from HBM for that token."""
+        if self.layer_kinds:
+            # A token's top-k experts lie anywhere among n_experts; the
+            # held share of them is what this chip computes, on average.
+            one = 2 * self.moe_latent * self.moe_intermediate
+            idle = self.n_held - (
+                self.n_active_experts * self.n_held / max(self.n_experts, 1)
+            )
+            n_e = sum(k == "E" for k in self.layer_kinds)
+            return int(self.param_count() - n_e * idle * one)
         if self.n_experts <= 0:
             return self.param_count()
         E, F = self.hidden_size, self.intermediate_size
@@ -127,6 +227,13 @@ def init_params(
     matching ``quantize_params``.
     """
     dtype = dtype or cfg.dtype
+    if cfg.layer_kinds:
+        if quantize:
+            raise ValueError(
+                f"{cfg.name} ({cfg.family}): weight quantization is not built "
+                "for Mamba-2 and latent-expert layers; serve it in bfloat16"
+            )
+        return _init_layer_kinds(cfg, key, dtype)
     E, F, V, L = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size, cfg.n_layers
     keys = jax.random.split(key, 8)
 
@@ -202,6 +309,74 @@ def init_params(
     return params
 
 
+def _init_layer_kinds(cfg: ModelConfig, key: jax.Array, dtype: Any) -> Dict[str, Any]:
+    """Random init of a ``layer_kinds`` stack: one dict per layer, in the
+    published order (``"norm"`` and the mixer's own sub-tree: ``"ssm"``,
+    ``"moe"`` or ``"attn"``). ``A``, ``dt_bias`` and ``D`` as the Mamba-2
+    family initialises them: A uniform in 1..16, dt log-uniform in
+    [1e-3, 1e-1] floored at 1e-4, D = 1."""
+    E, V = cfg.hidden_size, cfg.vocab_size
+
+    def dense(k, shape, fan_in, dt=dtype):
+        return (jax.random.normal(k, shape, dtype=jnp.float32) * fan_in**-0.5).astype(dt)
+
+    def norm():
+        return {"scale": jnp.ones((E,), dtype)}
+
+    layers = []
+    for l, kind in enumerate(cfg.layer_kinds):
+        k = jax.random.split(jax.random.fold_in(key, l + 1), 8)
+        if kind == "*":
+            mixer = {"attn": {
+                "wq": dense(k[0], (E, cfg.q_dim), E),
+                "wk": dense(k[1], (E, cfg.kv_dim), E),
+                "wv": dense(k[2], (E, cfg.kv_dim), E),
+                "wo": dense(k[3], (cfg.q_dim, E), cfg.q_dim),
+            }}
+        elif kind == "M":
+            I, H, C = cfg.ssm_inner, cfg.ssm_heads, cfg.ssm_conv_dim
+            dt = jnp.maximum(jnp.exp(
+                jax.random.uniform(k[2], (H,)) * (np.log(0.1) - np.log(1e-3))
+                + np.log(1e-3)
+            ), 1e-4)
+            mixer = {"ssm": {
+                "in_proj": dense(k[0], (E, I + C + H), E),
+                "conv_w": dense(k[1], (cfg.ssm_conv, C), cfg.ssm_conv),
+                "conv_b": jnp.zeros((C,), dtype),
+                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),     # softplus^-1
+                "A_log": jnp.log(jax.random.uniform(k[3], (H,), minval=1.0, maxval=16.0)),
+                "D": jnp.ones((H,), jnp.float32),
+                "norm": jnp.ones((I,), dtype),
+                "out_proj": dense(k[4], (I, E), I),
+            }}
+        elif kind == "E":
+            X, Z, F = cfg.n_held, cfg.moe_latent, cfg.moe_intermediate
+            S = cfg.moe_shared_intermediate
+            mixer = {"moe": {
+                # float32: its scores pick which experts run
+                "router": dense(k[0], (E, cfg.n_experts), E, jnp.float32),
+                "bias": jnp.zeros((cfg.n_experts,), jnp.float32),
+                "w_in": dense(k[1], (E, Z), E),
+                "w_up": dense(k[2], (X, Z, F), Z),
+                "w_down": dense(k[3], (X, F, Z), F),
+                "w_out": dense(k[4], (Z, E), Z),
+                "shared": {
+                    "wu": dense(k[5], (E, S), E), "wd": dense(k[6], (S, E), S),
+                },
+            }}
+        else:
+            raise ValueError(f"unknown layer kind {kind!r}")
+        layers.append(dict(mixer, norm=norm()))
+    params: Dict[str, Any] = {
+        "embed": dense(jax.random.fold_in(key, 0), (V, E), 1.0),
+        "layers": tuple(layers),
+        "final_norm": norm(),
+    }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = dense(jax.random.fold_in(key, 10_000), (E, V), E)
+    return params
+
+
 def param_logical_axes(cfg: ModelConfig) -> Dict[str, Any]:
     """Parallel pytree of logical-axis tuples for ``shard_params``.
 
@@ -209,6 +384,12 @@ def param_logical_axes(cfg: ModelConfig) -> Dict[str, Any]:
     heads/mlp/vocab over the ``model`` mesh axis; FSDP shards the embed
     axis; see ``parallel/sharding.DEFAULT_RULES``.
     """
+    if cfg.layer_kinds:
+        raise NotImplementedError(
+            f"{cfg.name} ({cfg.family}): a stack of Mamba-2 and latent-expert "
+            "layers runs on one chip (its share is ModelConfig.experts_held); "
+            "the exchange across chips is not built (ROADMAP Queue 2 item 2)"
+        )
     layers: Dict[str, Any] = {
         "ln1": {"scale": ("layers", None)},
         "ln2": {"scale": ("layers", None)},

@@ -4,7 +4,7 @@ from __future__ import annotations
 
 from typing import Dict, List
 
-from pilottai_tpu.models import gemma, llama
+from pilottai_tpu.models import gemma, llama, nemotron_h
 from pilottai_tpu.models.common import ModelConfig
 
 _REGISTRY: Dict[str, ModelConfig] = {}
@@ -29,6 +29,7 @@ for _cfg in (
     gemma.GEMMA2_2B,
     gemma.GEMMA_2B_BYTE,
     gemma.GEMMA_TINY,
+    nemotron_h.NEMOTRON_H_TINY,
 ):
     register_model(_cfg)
 

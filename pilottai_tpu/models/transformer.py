@@ -37,6 +37,8 @@ from pilottai_tpu.parallel.sharding import with_logical_constraint
 def _activation(cfg: ModelConfig, x: jax.Array) -> jax.Array:
     if cfg.act == "gelu_tanh":
         return jax.nn.gelu(x, approximate=True)
+    if cfg.act == "relu2":
+        return jnp.square(jax.nn.relu(x))
     return jax.nn.silu(x)
 
 
@@ -53,6 +55,10 @@ def _mlp(
 
         return moe_mlp(cfg, lp["moe"], x, lambda h: _activation(cfg, h))
     p = lp["mlp"]
+    if not cfg.mlp_gated:
+        with jax.named_scope("mlp"):
+            up = _activation(cfg, qmatmul(x, p["wu"]))
+            return qmatmul(up, p["wd"]), jnp.zeros((), jnp.float32)
     with jax.named_scope("mlp"):
         gate = _activation(cfg, qmatmul(x, p["wg"]))
         up = qmatmul(x, p["wu"])
@@ -75,8 +81,9 @@ def _qkv(
     q = qmatmul(x, p["wq"]).reshape(B, T, cfg.n_heads, cfg.head_dim)
     k = qmatmul(x, p["wk"]).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
     v = qmatmul(x, p["wv"]).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
-    q = apply_rope(q, sin, cos)
-    k = apply_rope(k, sin, cos)
+    if cfg.rope:
+        q = apply_rope(q, sin, cos)
+        k = apply_rope(k, sin, cos)
     return q, k, v
 
 

@@ -53,6 +53,75 @@ def dequantize_kv(q: jax.Array, scale: jax.Array, dtype) -> jax.Array:
     return (q.astype(jnp.float32) * scale[..., None].astype(jnp.float32)).astype(dtype)
 
 
+class StatePool(NamedTuple):
+    """Per-slot state of the layers that keep no KV (``ModelConfig.
+    layer_kinds``' ``M`` layers, ``models/ssm.py``), beside the KV of the
+    attention layers in the same cache object: the cache is what every
+    admission and decode dispatch donates and gets back, so the pool rides
+    with it and has one owner.
+
+    An admission OVERWRITES its slot's rows (a fresh prompt starts from
+    zeros inside the program, never from what the slot held), decode steps
+    update the rows of live slots in place, and a row that is not live
+    stands still. So a released slot needs no clearing.
+
+    ``routed`` is no state of a slot: two running counts, summed on the
+    device by the expert layers (token-expert pairs routed; those that
+    landed on experts held here), wrapping at 2**32; the batcher reads
+    their differences where it counts decode steps."""
+
+    conv: Tuple[jax.Array, ...]   # per M layer [B, conv - 1, channels]
+    ssm: Tuple[jax.Array, ...]    # per M layer [B, heads, head_dim, state] f32
+    routed: jax.Array             # [2] uint32
+
+    @classmethod
+    def create(cls, cfg, n_slots: int, dtype=jnp.bfloat16) -> Optional["StatePool"]:
+        """The pool ``cfg`` needs; None for a model whose layers all keep
+        KV and route nothing to count."""
+        if not cfg.layer_kinds:
+            return None
+        n = sum(k == "M" for k in cfg.layer_kinds)
+        return cls(
+            conv=tuple(
+                jnp.zeros((n_slots, cfg.ssm_conv - 1, cfg.ssm_conv_dim), dtype)
+                for _ in range(n)
+            ),
+            ssm=tuple(
+                jnp.zeros(
+                    (n_slots, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
+                    jnp.float32,
+                )
+                for _ in range(n)
+            ),
+            routed=jnp.zeros((2,), jnp.uint32),
+        )
+
+    def rows(self, slots: jax.Array, fresh: jax.Array):
+        """``(conv, ssm)`` of ``slots`` (out-of-range rows read row 0),
+        zeros where ``fresh``: a prompt's first tokens start from nothing,
+        whatever the slot's last occupant left."""
+        def take(a):
+            got = a[jnp.clip(slots, 0, a.shape[0] - 1)]
+            keep = ~jnp.broadcast_to(fresh, slots.shape)
+            return jnp.where(keep.reshape((-1,) + (1,) * (a.ndim - 1)), got, 0)
+
+        return tuple(take(a) for a in self.conv), tuple(take(a) for a in self.ssm)
+
+    def write(self, slots: jax.Array, conv, ssm, routed: jax.Array) -> "StatePool":
+        """Overwrite the rows of ``slots`` (out-of-range rows are dropped)
+        and add ``routed`` to the running counts."""
+        return StatePool(
+            conv=tuple(
+                a.at[slots].set(c.astype(a.dtype), mode="drop")
+                for a, c in zip(self.conv, conv)
+            ),
+            ssm=tuple(
+                a.at[slots].set(s, mode="drop") for a, s in zip(self.ssm, ssm)
+            ),
+            routed=self.routed + routed,
+        )
+
+
 class KVCache(NamedTuple):
     layers: Tuple[Tuple[jax.Array, jax.Array], ...]  # per-layer (k, v) [B, K, S, H]
     lengths: jax.Array                               # [B] int32 — valid entries
@@ -61,6 +130,9 @@ class KVCache(NamedTuple):
     # Decode is HBM-bound and the cache is ~1/3 of its traffic at short
     # contexts — int8 halves that for ~1e-3 relative attention error.
     scales: Optional[Tuple[Tuple[jax.Array, jax.Array], ...]] = None
+    # State of the layers that keep no KV; ``layers`` then holds the
+    # attention layers only. None for a model whose layers all keep KV.
+    state: Optional[StatePool] = None
 
     @property
     def n_layers(self) -> int:
@@ -92,6 +164,7 @@ class KVCache(NamedTuple):
         head_dim: int,
         dtype=jnp.bfloat16,
         quantized: bool = False,
+        state: Optional[StatePool] = None,
     ) -> "KVCache":
         shape = (n_slots, n_kv_heads, max_len, head_dim)
         store_dtype = jnp.int8 if quantized else dtype
@@ -110,7 +183,7 @@ class KVCache(NamedTuple):
         )
         return cls(
             layers=layers, lengths=jnp.zeros((n_slots,), dtype=jnp.int32),
-            scales=scales,
+            scales=scales, state=state,
         )
 
 

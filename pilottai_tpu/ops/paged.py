@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from pilottai_tpu.ops.kvcache import quantize_kv
+from pilottai_tpu.ops.kvcache import StatePool, quantize_kv
 
 
 class PagedKVCache(NamedTuple):
@@ -48,6 +48,10 @@ class PagedKVCache(NamedTuple):
     # pools are int8 (symmetric per-token-per-head); None otherwise.
     # Halves decode cache traffic and doubles resident context per HBM GB.
     scales: Optional[Tuple[Tuple[jax.Array, jax.Array], ...]] = None
+    # State of the layers that keep no KV, per slot and not paged
+    # (ops/kvcache.py:StatePool); ``layers`` then holds the attention
+    # layers only. None for a model whose layers all keep KV.
+    state: Optional[StatePool] = None
 
     @property
     def n_layers(self) -> int:
@@ -84,6 +88,7 @@ class PagedKVCache(NamedTuple):
         head_dim: int,
         dtype=jnp.bfloat16,
         quantized: bool = False,
+        state: Optional[StatePool] = None,
     ) -> "PagedKVCache":
         shape = (n_kv_heads, num_pages, page_size, head_dim)
         store_dtype = jnp.int8 if quantized else dtype
@@ -102,7 +107,7 @@ class PagedKVCache(NamedTuple):
         )
         return cls(
             layers=layers, lengths=jnp.zeros((n_slots,), dtype=jnp.int32),
-            scales=scales,
+            scales=scales, state=state,
         )
 
 
