@@ -53,6 +53,70 @@ def dequantize_kv(q: jax.Array, scale: jax.Array, dtype) -> jax.Array:
     return (q.astype(jnp.float32) * scale[..., None].astype(jnp.float32)).astype(dtype)
 
 
+def write_rows(store: jax.Array, idx: jax.Array, rows: jax.Array) -> jax.Array:
+    """Write ``rows`` into the donated KV ``store`` in place: THE write of
+    every chunk end and every paged admission, for panels, page pools and
+    their scale pools alike.
+
+    ``store`` is ``[b.., i.., w..]``, ``idx`` ``[b.., R, n_i]`` int32 and
+    ``rows`` ``[b.., R, w..]``: row ``r`` of batch ``b..`` lands at
+    ``store[b.., idx[b.., r, 0], .., idx[b.., r, n_i - 1]]``; a row whose
+    index lies outside ``store`` is dropped. The leading dimensions are
+    batching dimensions of the scatter (kv-heads of a pool; slots and
+    kv-heads of a dense panel) and the indexed ones follow them, so no
+    compiler has to move a dimension to reach a row: the TPU's folds
+    batch and index into one row number and updates the buffer it was
+    given, the CPU's scatters in place, and the partitioner of a serving
+    mesh hands each shard its own heads' rows with no collective.
+
+    What this replaced: ``pool.at[:, pages, off].set(...)`` (and the dense
+    ``k.at[slot, :, pos]``), an advanced-index scatter whose indexed
+    dimensions did not lead. XLA moves those to the front, so the whole
+    pool was transposed, scattered into and copied back on every
+    dispatch: ``copy_s8_8_193_128_128`` 0.638 s and ``copy_f32_8_193_128``
+    0.073 s of a 3 s slice, 12 ms of every 40 ms decode step at 7B
+    (ledger, PR 29, ``mistral-7b.agent-loop``). A ``dynamic_update_slice``
+    a row is in place too but is 1,536 operations a step there, and a
+    Pallas DMA of one row is refused for int8 and bfloat16, whose rows
+    share a 32-bit sublane word with their neighbours (PERF.md §6 PR 30).
+    """
+    n_batch = idx.ndim - 2
+    n_idx = idx.shape[-1]
+    return jax.lax.scatter(
+        store, idx, rows.astype(store.dtype),
+        jax.lax.ScatterDimensionNumbers(
+            update_window_dims=tuple(range(n_batch + 1, rows.ndim)),
+            inserted_window_dims=tuple(range(n_batch, n_batch + n_idx)),
+            scatter_dims_to_operand_dims=tuple(range(n_batch, n_batch + n_idx)),
+            operand_batching_dims=tuple(range(n_batch)),
+            scatter_indices_batching_dims=tuple(range(n_batch)),
+        ),
+        mode=jax.lax.GatherScatterMode.FILL_OR_DROP,
+    )
+
+
+def write_layers(cache, idx: jax.Array, new_kv) -> dict:
+    """``write_rows`` over every layer of ``cache`` (dense or paged):
+    ``new_kv`` yields each layer's ``(k, v)`` rows in compute precision,
+    quantized here when the store is int8 (same ``quantize_kv`` on the same
+    values wherever they land). Returns the fields to ``_replace``."""
+    layers = []
+    scales = [] if cache.scales is not None else None
+    for li, ((k, v), (k_new, v_new)) in enumerate(zip(cache.layers, new_kv)):
+        if scales is not None:
+            k_new, ksc = quantize_kv(k_new)
+            v_new, vsc = quantize_kv(v_new)
+            ks_p, vs_p = cache.scales[li]
+            scales.append(
+                (write_rows(ks_p, idx, ksc), write_rows(vs_p, idx, vsc))
+            )
+        layers.append((write_rows(k, idx, k_new), write_rows(v, idx, v_new)))
+    return dict(
+        layers=tuple(layers),
+        scales=tuple(scales) if scales is not None else None,
+    )
+
+
 class StatePool(NamedTuple):
     """Per-slot state of the layers that keep no KV (``ModelConfig.
     layer_kinds``' ``M`` layers, ``models/ssm.py``), beside the KV of the
@@ -203,12 +267,16 @@ def write_prompts(
     out-of-bounds slot index so XLA scatter semantics drop them.
     """
     A = ks.shape[1]
-    # dynamic_update_slice (not scatter): XLA aliases it in place on the
-    # donated cache, where an advanced-index scatter measured a full-cache
-    # copy per admission. dus clamps out-of-range starts instead of
-    # dropping, so padding rows are routed to the *first* row's slot and
-    # written before it (reversed order) — row 0 is always a live request,
-    # and its later write overwrites the padding garbage.
+    # One dynamic_update_slice a row: a whole [K, T, H] panel head is a
+    # slice, XLA aliases it in place on the donated cache, and A rows a
+    # layer are few. (``slots`` index the LEADING dimension, so a scatter
+    # here would be in place as well; what copied the whole store, 12 ms of
+    # a 40 ms decode step at 7B by the ledger's PR 29 line, was a scatter
+    # whose indexed dimensions did not lead: ``write_rows``.) dus clamps
+    # out-of-range starts instead of dropping, so padding rows are routed
+    # to the *first* row's slot and written before it (reversed order):
+    # row 0 is always a live request, and its later write overwrites the
+    # padding garbage.
     safe_slots = jnp.where(lengths > 0, slots, slots[0])
     new_layers = []
     new_scales = [] if cache.scales is not None else None
@@ -252,44 +320,21 @@ def write_chunk_rows(
     start: jax.Array,      # [B] int32 — slot length at chunk start
     accepted: jax.Array,   # [B] int32 — rows actually generated this chunk
 ) -> KVCache:
-    """Scatter one decode chunk's ring buffers into the big cache.
+    """Write one decode chunk's ring buffers into the panels, in place
+    (``write_rows``: slots and kv-heads batch, the position is the index).
 
     Row j of slot b lands at position start[b] + j when j < accepted[b];
-    rejected rows (beyond EOS/budget) are routed past S and dropped.
+    rejected rows (beyond EOS/budget) are routed past S and dropped, so
+    the panel stays as it was there.
     """
-    B = cache.n_slots
     S = cache.max_len
-    n = ring_ks[0].shape[2]
+    B, K, n, _ = ring_ks[0].shape
     j = jnp.arange(n)[None, :]                               # [1, n]
     pos = jnp.where(j < accepted[:, None], start[:, None] + j, S)  # [B, n]
-    bidx = jnp.arange(B)[:, None]
-    new_layers = []
-    new_scales = [] if cache.scales is not None else None
-    for li, ((k, v), rk, rv) in enumerate(zip(cache.layers, ring_ks, ring_vs)):
-        if cache.scales is not None:
-            rk, ksc = quantize_kv(rk)                        # [B, K, n]
-            rv, vsc = quantize_kv(rv)
-            ks_p, vs_p = cache.scales[li]
-            ks_p = ks_p.at[bidx, :, pos].set(
-                ksc.transpose(0, 2, 1), mode="drop"
-            )
-            vs_p = vs_p.at[bidx, :, pos].set(
-                vsc.transpose(0, 2, 1), mode="drop"
-            )
-            new_scales.append((ks_p, vs_p))
-        # Advanced indices (bidx, pos) broadcast to [B, n]; the kv-head
-        # slice rides along -> update values [B, n, K, H].
-        k = k.at[bidx, :, pos].set(
-            rk.transpose(0, 2, 1, 3).astype(k.dtype), mode="drop"
-        )
-        v = v.at[bidx, :, pos].set(
-            rv.transpose(0, 2, 1, 3).astype(v.dtype), mode="drop"
-        )
-        new_layers.append((k, v))
-    new_lengths = jnp.minimum(cache.lengths + accepted, S)
+    idx = jnp.broadcast_to(pos[:, None, :, None], (B, K, n, 1))
     return cache._replace(
-        layers=tuple(new_layers), lengths=new_lengths,
-        scales=tuple(new_scales) if new_scales is not None else None,
+        lengths=jnp.minimum(cache.lengths + accepted, S),
+        **write_layers(cache, idx, zip(ring_ks, ring_vs)),
     )
 
 

@@ -16,10 +16,14 @@ Division of labor:
   just "not enough free pages → request stays pending".
 * **Pages are allocated for prompt + full generation budget up front**,
   so no mid-decode growth path exists; completion frees them all.
-* Device ops here mirror the dense API: batched prompt scatter, ring
-  scatter at chunk end, gather-based prefix attention reads (the Pallas
-  paged-attention kernel in ``ops/pallas/paged_attention.py`` replaces
-  the gather on TPU).
+* Device ops here mirror the dense API: prompts written a page at a
+  time, the chunk ring written a row at a time at chunk end, both in
+  place on the donated pools (``ops/kvcache.py:write_rows``; the
+  advanced-index scatter they replace copied the whole pool on every
+  dispatch, 27% of the device's busy time on ``mistral-7b.agent-loop``
+  by the ledger's PR 29 line), gather-based prefix attention reads (the
+  Pallas paged-attention kernel in ``ops/pallas/paged_attention.py``
+  replaces the gather on TPU).
 
 Design follows the ragged/paged attention literature cited in PAPERS.md;
 it replaces the docstring-only "paged variant" of round 1. No reference counterpart (the reference has no KV anything —
@@ -34,12 +38,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from pilottai_tpu.ops.kvcache import StatePool, quantize_kv
+from pilottai_tpu.ops.kvcache import StatePool, write_layers
 
 
 class PagedKVCache(NamedTuple):
     # per-layer (k_pool, v_pool), each [K, num_pages, P, H]. The LAST page
-    # (index num_pages - 1) is a scratch page: scatter targets for dropped
+    # (index num_pages - 1) is a scratch page: the target of dropped
     # writes and gather source for unallocated table slots — never handed
     # to the allocator.
     layers: Tuple[Tuple[jax.Array, jax.Array], ...]
@@ -221,55 +225,37 @@ def write_prompts_paged(
                           # position of row 0 (page-ALIGNED; prefix-cached
                           # tail writes land after the shared pages)
 ) -> PagedKVCache:
-    """Scatter freshly prefilled prompts into their slots' pages. T (the
-    prefill bucket) need not be page-aligned; positions past ``lengths``
-    land on allocated-but-masked space or on the sentinel scratch page."""
-    L, A, T, K, H = ks.shape
+    """Write freshly prefilled prompts into their slots' pages, a page at
+    a time and in place (``write_rows``). T (the prefill bucket) need not
+    be page-aligned. A row's pages up to the one that holds its last
+    position go through its table (what that page holds past ``lengths``
+    is the padding's K/V: allocated, masked, and overwritten as the slot
+    decodes); every page past it, a padding row's, and any block past the
+    table's width go to the sentinel scratch page, so no page of another
+    slot or of the prefix index is ever touched."""
+    _, A, T, K, H = ks.shape
     P = cache.page_size
     n_blocks = -(-T // P)
     Tp = n_blocks * P
-    pos = jnp.arange(Tp)                                     # [Tp]
-    live = pos[None, :] < lengths[:, None]                   # [A, Tp]
+    blk = jnp.arange(n_blocks)                               # [nb]
+    live = (blk * P)[None, :] < lengths[:, None]             # [A, nb]
     if pos_offset is not None:
-        pos = pos + pos_offset
-    max_pos = table.shape[1] * P - 1
-    blk = jnp.minimum(pos, max_pos) // P
-    # Page id per (row, position); sentinel when the position is beyond
-    # the row's valid length or its allocation.
-    pages = jnp.take_along_axis(
-        table, jnp.broadcast_to(blk[None, :], (A, Tp)), axis=1
-    )                                                        # [A, Tp]
-    pages = jnp.where(live, pages, cache.num_pages - 1)
-    off = jnp.broadcast_to((pos % P)[None, :], (A, Tp))
-    pages_f = pages.reshape(-1)                              # [A*Tp]
-    off_f = off.reshape(-1)
-
-    new_layers = []
-    new_scales = [] if cache.scales is not None else None
-    for li, (kp, vp) in enumerate(cache.layers):
-        # [A, T, K, H] -> pad T to Tp -> [K, A*Tp, H]
-        k_new = ks[li]
-        v_new = vs[li]
-        if Tp != T:
-            pad = ((0, 0), (0, Tp - T), (0, 0), (0, 0))
-            k_new = jnp.pad(k_new, pad)
-            v_new = jnp.pad(v_new, pad)
-        k_new = k_new.transpose(2, 0, 1, 3).reshape(K, A * Tp, H)
-        v_new = v_new.transpose(2, 0, 1, 3).reshape(K, A * Tp, H)
-        if cache.scales is not None:
-            k_new, ksc = quantize_kv(k_new)                  # [K, A*Tp]
-            v_new, vsc = quantize_kv(v_new)
-            ks_p, vs_p = cache.scales[li]
-            ks_p = ks_p.at[:, pages_f, off_f].set(ksc, mode="drop")
-            vs_p = vs_p.at[:, pages_f, off_f].set(vsc, mode="drop")
-            new_scales.append((ks_p, vs_p))
-        kp = kp.at[:, pages_f, off_f].set(k_new.astype(kp.dtype), mode="drop")
-        vp = vp.at[:, pages_f, off_f].set(v_new.astype(vp.dtype), mode="drop")
-        new_layers.append((kp, vp))
-    return cache._replace(
-        layers=tuple(new_layers),
-        scales=tuple(new_scales) if new_scales is not None else None,
+        blk = blk + pos_offset // P
+    live &= (blk < table.shape[1])[None, :]
+    pages = jnp.take(table, jnp.minimum(blk, table.shape[1] - 1), axis=1)
+    pages = jnp.where(live, pages, cache.num_pages - 1)      # [A, nb]
+    idx = jnp.broadcast_to(
+        pages.reshape(1, A * n_blocks, 1), (K, A * n_blocks, 1)
     )
+
+    def by_page(new):     # [A, T, K, H] -> [K, A*nb, P, H]
+        if Tp != T:
+            new = jnp.pad(new, ((0, 0), (0, Tp - T), (0, 0), (0, 0)))
+        return new.transpose(2, 0, 1, 3).reshape(K, A * n_blocks, P, H)
+
+    return cache._replace(**write_layers(
+        cache, idx, ((by_page(k), by_page(v)) for k, v in zip(ks, vs))
+    ))
 
 
 def install_lengths(
@@ -293,10 +279,13 @@ def write_chunk_rows_paged(
     start: jax.Array,     # [B]
     accepted: jax.Array,  # [B]
 ) -> PagedKVCache:
-    """Chunk-end scatter of the decode ring into pages (paged counterpart
-    of ``ops/kvcache.py:write_chunk_rows``)."""
+    """Chunk-end write of the decode ring into pages, a row at a time and
+    in place (paged counterpart of ``ops/kvcache.py:write_chunk_rows``):
+    row j of slot b lands at ``(table[b, (start+j) // P], (start+j) % P)``
+    of every kv-head; rows past ``accepted`` go to the scratch page."""
     B = cache.n_slots
     P = cache.page_size
+    K = cache.n_kv_heads
     n = ring_ks[0].shape[2]
     j = jnp.arange(n)[None, :]
     pos = start[:, None] + j                                 # [B, n]
@@ -304,30 +293,12 @@ def write_chunk_rows_paged(
     blk = jnp.minimum(pos, max_pos) // P
     pages = jnp.take_along_axis(table, blk, axis=1)          # [B, n]
     pages = jnp.where(j < accepted[:, None], pages, cache.num_pages - 1)
-    pages_f = pages.reshape(-1)                              # [B*n]
-    off_f = (pos % P).reshape(-1)
+    idx = jnp.stack([pages, pos % P], axis=-1).reshape(1, B * n, 2)
+    idx = jnp.broadcast_to(idx, (K, B * n, 2))
 
-    new_layers = []
-    new_scales = [] if cache.scales is not None else None
-    for li, ((kp, vp), rk, rv) in enumerate(
-        zip(cache.layers, ring_ks, ring_vs)
-    ):
-        k_new = rk.transpose(1, 0, 2, 3).reshape(
-            cache.n_kv_heads, B * n, cache.head_dim
-        )
-        v_new = rv.transpose(1, 0, 2, 3).reshape(
-            cache.n_kv_heads, B * n, cache.head_dim
-        )
-        if cache.scales is not None:
-            k_new, ksc = quantize_kv(k_new)
-            v_new, vsc = quantize_kv(v_new)
-            ks_p, vs_p = cache.scales[li]
-            ks_p = ks_p.at[:, pages_f, off_f].set(ksc, mode="drop")
-            vs_p = vs_p.at[:, pages_f, off_f].set(vsc, mode="drop")
-            new_scales.append((ks_p, vs_p))
-        kp = kp.at[:, pages_f, off_f].set(k_new.astype(kp.dtype), mode="drop")
-        vp = vp.at[:, pages_f, off_f].set(v_new.astype(vp.dtype), mode="drop")
-        new_layers.append((kp, vp))
+    def rows(ring):       # [B, K, n, H] -> [K, B*n, H]
+        return ring.transpose(1, 0, 2, 3).reshape(K, B * n, cache.head_dim)
+
     # Clamp to allocated slot capacity (parity with the dense path's min
     # against S): decode's ctx_full/budget invariants should keep lengths
     # in range on their own, but a length past allocation would claim
@@ -336,8 +307,10 @@ def write_chunk_rows_paged(
         cache.lengths + jnp.minimum(accepted, n), table.shape[1] * P
     )
     return cache._replace(
-        layers=tuple(new_layers), lengths=new_lengths,
-        scales=tuple(new_scales) if new_scales is not None else None,
+        lengths=new_lengths,
+        **write_layers(
+            cache, idx, ((rows(rk), rows(rv)) for rk, rv in zip(ring_ks, ring_vs))
+        ),
     )
 
 

@@ -65,7 +65,7 @@ def _paged_kernel(
     #   q_ref      VMEM (1, K, G, H)
     #   k_refs × n_strip   VMEM (K, 1, P, H) — one page each
     #   v_refs × n_strip   VMEM (K, 1, P, H)
-    #   [ks/vs_refs × n_strip  VMEM (K, 1, P, 1) when quantized]
+    #   [ks/vs_refs × n_strip  VMEM (1, K, P) when quantized]
     #   [ringk_ref, ringv_ref  VMEM (1, K, R, H) when ring]
     #   acc_ref (1, K, G, H) f32, m_ref (1, K, G, 1), l_ref (1, K, G, 1)
     scale: float,
@@ -109,23 +109,32 @@ def _paged_kernel(
     qpos = qpos_ref[b]
 
     def _attend_page(k_ref, v_ref, ks_ref, vs_ref, j0):
-        """One page's online-softmax update — byte-identical math to the
-        pre-strip single-page kernel (the parity suite pins this)."""
+        """One page's online-softmax update — the same math page for
+        page whatever the strip (the parity suite pins this)."""
         q = q_ref[0]                                      # [K, G, H]
         k = k_ref[:, 0]                                   # [K, P, H]
         v = v_ref[:, 0]
         if quantized:
-            # In-VMEM dequant: the HBM→VMEM stream stays int8-sized.
-            # Scale blocks ride as (K, 1, P, 1) — the trailing singleton
-            # satisfies the TPU lowering's last-two-dims constraint.
-            k = k.astype(jnp.float32) * ks_ref[:, 0]
-            v = v.astype(jnp.float32) * vs_ref[:, 0]
+            # The HBM→VMEM stream stays int8-sized, and a page's scales
+            # come as one [K, P] block with P on the lanes: a key's
+            # scale multiplies its SCORE (``q·(k·s) = (q·k)·s``) and a
+            # value's scale its probability, both [K, G, P] with P on
+            # the lanes too, so nothing is transposed in here and no
+            # pool is re-laid in front of the call (a trailing singleton
+            # on the scale pools cost a padded copy of each, 128 times
+            # its size, a layer a step: PERF.md §6 PR 30).
+            k = k.astype(jnp.float32)
+            v = v.astype(jnp.float32)
             q = q.astype(jnp.float32)
+            k_sc = ks_ref[0][:, None, :]                  # [K, 1, P]
+            v_sc = vs_ref[0][:, None, :]
         s = jax.lax.dot_general(
             q, k,
             dimension_numbers=(((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         ) * scale                                         # [K, G, P]
+        if quantized:
+            s = s * k_sc
         if softcap > 0.0:
             s = jnp.tanh(s / softcap) * softcap
         col = j0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
@@ -150,6 +159,8 @@ def _paged_kernel(
             m_prev > NEG_INF / 2, jnp.exp(m_prev - m_new), 0.0
         )
         l_ref[0, :, :, :] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+        if quantized:
+            p = p * v_sc
         pv = jax.lax.dot_general(
             p.astype(v.dtype), v,
             dimension_numbers=(((2,), (1,)), ((0,), (0,))),
@@ -317,18 +328,21 @@ def paged_decode_attention(
     in_specs += [pl.BlockSpec((K, 1, P, H), page_map(t)) for t in range(n_strip)]
     operands += [v_pool] * n_strip
     if quantized:
-        # Trailing singleton: TPU lowering requires the last two block
-        # dims be (8k, 128k) or equal the array dims — (P, 1) qualifies.
-        ks_op = k_scales.astype(jnp.float32)[..., None]
-        vs_op = v_scales.astype(jnp.float32)[..., None]
-        in_specs += [
-            pl.BlockSpec((K, 1, P, 1), page_map(t)) for t in range(n_strip)
-        ]
-        operands += [ks_op] * n_strip
-        in_specs += [
-            pl.BlockSpec((K, 1, P, 1), page_map(t)) for t in range(n_strip)
-        ]
-        operands += [vs_op] * n_strip
+        # A page's scales are one (K, P) block of the pool seen page-major:
+        # TPU lowering wants a block's last two dims (8k, 128k) or the
+        # array's own, and (K, P) is the array's. With 8 kv-heads the
+        # chip keeps [K, pages, P] float32 page-major already (K fills
+        # the 8 sublanes of a tile), so the view moves no byte.
+        def scale_map(t):
+            def _map(b, j, table_ref, *_):
+                return page_map(t)(b, j, table_ref)[1:]
+            return _map
+
+        for pool in (k_scales, v_scales):
+            in_specs += [
+                pl.BlockSpec((1, K, P), scale_map(t)) for t in range(n_strip)
+            ]
+            operands += [pool.astype(jnp.float32).transpose(1, 0, 2)] * n_strip
     scalars = [table, last_valid, q_positions]
     if ring:
         R = ring_k.shape[2]
@@ -375,14 +389,17 @@ def strip_vmem_bytes(
     n_strip: int, page_size: int, n_kv_heads: int, head_dim: int,
     itemsize: int, quantized: bool,
 ) -> int:
-    """VMEM the strip's K/V (and scale) blocks pin per pipeline stage.
+    """VMEM the strip's K/V (and scale) blocks pin per pipeline stage,
+    and room for the kernel's float32 copy of an int8 page.
 
-    Scale blocks ride as ``(K, 1, P, 1)`` float32 (see ``_attend_page``):
-    the trailing singleton pads to a full 128-lane tile in VMEM, so one
-    scale block costs as much as a float32 page of head_dim 128 — four
-    times the int8 page it scales. Counting it as ``K*P*4`` bytes let the
-    autotuner offer an int8 pool a strip of 8 pages of 128 that the
-    chip's compiler refuses (21 MB of VMEM)."""
+    A scale block is ``(1, K, P)`` float32 (4 KB at 8 heads and pages of
+    128) since PR 30; it rode as ``(K, 1, P, 1)``, whose trailing
+    singleton padded it to a float32 page of head_dim 128, and is still
+    COUNTED at that size: the kernel dequantizes a page to float32 before
+    its dots, which takes as much again, and with the count at ``K*P*4``
+    bytes the autotuner once offered an int8 pool a strip of 8 pages of
+    128 that the chip's compiler refuses (21 MB of VMEM). Which strips an
+    int8 pool can now take is a tuning question of its own."""
     kv = 2 * n_kv_heads * page_size * head_dim * itemsize
     sc = 2 * n_kv_heads * page_size * _LANES * 4 if quantized else 0
     return n_strip * (kv + sc)

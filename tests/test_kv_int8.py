@@ -26,6 +26,8 @@ from pilottai_tpu.ops.kvcache import (
     write_prompts,
 )
 
+import kv_write_oracle as oracle
+
 
 def test_quantize_roundtrip_is_lossless_fixpoint():
     """dequantize → requantize must be exact (same scale recomputed) —
@@ -99,6 +101,51 @@ async def _gen(prompts, **cfg_kw):
 
 PRE = ("You are the orchestrator. Analyze the task and respond with "
        "strict JSON as instructed by the rules preamble. Task: ")
+
+
+@pytest.mark.parametrize("accepted", sorted(oracle.ACCEPTED))
+@pytest.mark.parametrize("n", [1, 4, 6], ids=["chunk1", "chunk4", "spec2x3"])
+@pytest.mark.parametrize("quantized", [True, False], ids=["int8", "bf16"])
+def test_chunk_rows_dense_bit_identical_to_old_scatter(quantized, n, accepted):
+    """The in-place chunk-end write of the dense panels against the scatter
+    it replaced (PR 30), on panels full of random bytes: every panel,
+    every scale and ``lengths`` come out the same, bit for bit; slot 1
+    stands at the end of its panel, so its rows past S are dropped, and a
+    row past ``accepted`` leaves the panel as it was."""
+    B, K, S, H, L = 4, 2, 64, 32, 2
+    rng = np.random.default_rng(n * 5 + len(accepted))
+    shape = (B, K, S, H)
+    if quantized:
+        panel = lambda: jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+    else:
+        panel = lambda: jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    start = jnp.asarray([10, S - 2, 0, 33], jnp.int32)
+    cache = KVCache(
+        layers=tuple((panel(), panel()) for _ in range(L)),
+        lengths=start,
+        scales=tuple(
+            (jnp.asarray(rng.random(shape[:-1]), jnp.float32),
+             jnp.asarray(rng.random(shape[:-1]), jnp.float32))
+            for _ in range(L)
+        ) if quantized else None,
+    )
+    rings = [
+        jnp.asarray(rng.standard_normal((B, K, n, H)) * 3, jnp.bfloat16)
+        for _ in range(2 * L)
+    ]
+    acc = jnp.asarray(oracle.ACCEPTED[accepted](n), jnp.int32)
+    new = jax.jit(write_chunk_rows)(cache, rings[:L], rings[L:], start, acc)
+    old = jax.jit(oracle.write_chunk_rows)(cache, rings[:L], rings[L:], start, acc)
+    np.testing.assert_array_equal(np.asarray(new.lengths), np.asarray(old.lengths))
+    flat = lambda c: jax.tree.leaves((c.layers, c.scales))
+    for got, want, was in zip(flat(new), flat(old), flat(cache)):
+        np.testing.assert_array_equal(
+            np.asarray(got.astype(jnp.float32)), np.asarray(want.astype(jnp.float32))
+        )
+        if accepted == "none":
+            np.testing.assert_array_equal(
+                np.asarray(got.astype(jnp.float32)), np.asarray(was.astype(jnp.float32))
+            )
 
 
 @pytest.mark.asyncio

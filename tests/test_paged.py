@@ -26,8 +26,12 @@ from pilottai_tpu.ops.paged import (
     PageAllocator,
     PagedKVCache,
     gather_pages,
+    write_chunk_rows_paged,
+    write_prompts_paged,
 )
 from pilottai_tpu.ops.pallas.paged_attention import paged_decode_attention
+
+import kv_write_oracle as oracle
 
 
 # --------------------------------------------------------------------- #
@@ -269,6 +273,160 @@ def test_paged_chunk_matches_dense(prefix_bound):
     np.testing.assert_array_equal(
         np.asarray(dc.lengths), np.asarray(pc.lengths)
     )
+
+
+# --------------------------------------------------------------------- #
+# The in-place writes against the scatter they replaced (PR 30)
+# --------------------------------------------------------------------- #
+
+def _random_paged(rng, n_layers, B, num_pages, P, K, H, quantized, lengths):
+    """A pool full of random bytes, so that a write that lands where it
+    must not shows."""
+    shape = (K, num_pages, P, H)
+    if quantized:
+        pool = lambda: jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+    else:
+        pool = lambda: jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    return PagedKVCache(
+        layers=tuple((pool(), pool()) for _ in range(n_layers)),
+        lengths=jnp.asarray(lengths, jnp.int32),
+        scales=tuple(
+            (jnp.asarray(rng.random(shape[:-1]), jnp.float32),
+             jnp.asarray(rng.random(shape[:-1]), jnp.float32))
+            for _ in range(n_layers)
+        ) if quantized else None,
+    )
+
+
+def _pools(cache):
+    """Every pool and scale pool of the cache as numpy, by name."""
+    out = {}
+    for li, (kp, vp) in enumerate(cache.layers):
+        out[f"k{li}"], out[f"v{li}"] = kp, vp
+        if cache.scales is not None:
+            out[f"ks{li}"], out[f"vs{li}"] = cache.scales[li]
+    return {
+        name: np.asarray(a.astype(jnp.float32) if a.dtype == jnp.bfloat16 else a)
+        for name, a in out.items()
+    }
+
+
+@pytest.mark.parametrize("accepted", sorted(oracle.ACCEPTED))
+@pytest.mark.parametrize("n", [1, 4, 6], ids=["chunk1", "chunk4", "spec2x3"])
+@pytest.mark.parametrize("quantized", [True, False], ids=["int8", "bf16"])
+def test_chunk_rows_paged_bit_identical_to_old_scatter(quantized, n, accepted):
+    """Slot 0 and slot 3 share two prefix pages, slot 1 stands at the end
+    of its allocation, slot 2's table row is all sentinels, and one page
+    belongs to the prefix index alone. The in-place write leaves every
+    page but the scratch page exactly as the old scatter leaves it, the
+    shared and pinned pages as they were, and ``lengths`` the same."""
+    B, P, K, H, L, W = 4, 16, 2, 32, 2, 6
+    rng = np.random.default_rng(n * 7 + len(accepted))
+    alloc = PageAllocator(33, P, B, max_pages_per_slot=W)
+    shared = alloc.take(2)
+    pinned = alloc.take(1)
+    assert alloc.allocate(0, 60, prefix_pages=shared)
+    assert alloc.allocate(3, 50, prefix_pages=shared)
+    assert alloc.allocate(1, W * P)
+    start = [40, W * P - 2, 0, 33]
+    table = jnp.asarray(alloc.table)
+    rings = [
+        jnp.asarray(rng.standard_normal((B, K, n, H)) * 3, jnp.bfloat16)
+        for _ in range(2 * L)
+    ]
+    acc = jnp.asarray(oracle.ACCEPTED[accepted](n), jnp.int32)
+    cache = _random_paged(rng, L, B, 33, P, K, H, quantized, start)
+    before = _pools(cache)
+
+    args = (table, rings[:L], rings[L:], jnp.asarray(start, jnp.int32), acc)
+    new = jax.jit(write_chunk_rows_paged)(cache, *args)
+    old = jax.jit(oracle.write_chunk_rows_paged)(cache, *args)
+    np.testing.assert_array_equal(np.asarray(new.lengths), np.asarray(old.lengths))
+    got, want = _pools(new), _pools(old)
+    for name in want:
+        np.testing.assert_array_equal(got[name][:, :-1], want[name][:, :-1], name)
+        for page in shared + pinned:
+            np.testing.assert_array_equal(got[name][:, page], before[name][:, page])
+    if accepted != "none":
+        assert any((got[k] != before[k]).any() for k in got), "nothing written"
+
+
+@pytest.mark.parametrize("pos_offset", [None, 0, 16], ids=["nooff", "off0", "off16pages"])
+@pytest.mark.parametrize("T", [40, 32], ids=["unaligned", "aligned"])
+@pytest.mark.parametrize("quantized", [True, False], ids=["int8", "bf16"])
+def test_write_prompts_paged_bit_identical_to_old_scatter(quantized, T, pos_offset):
+    """Four rows: a prompt, a second one that (behind an offset) shares the
+    first's prefix pages, a padding row, and a short one. Every live
+    position's K, V and scale are what the old scatter wrote; a page
+    that no row's live positions reach (another slot's, the prefix
+    index's, the shared prefix behind the offset) is as it was."""
+    A, P, K, H, L, W, NP = 4, 16, 2, 32, 2, 20, 81
+    rng = np.random.default_rng(T + (pos_offset or 0))
+    lens = np.array([T - 3, T, 0, 3], np.int32)
+    first = 0 if not pos_offset else pos_offset
+    free = list(rng.permutation(NP - 1))
+    shared = [int(free.pop()) for _ in range(first)]
+    table = np.full((A, W), NP - 1, np.int32)
+    for a in (0, 1, 3):
+        table[a, :first] = shared
+        table[a, first:first + 3] = [int(free.pop()) for _ in range(3)]
+    ks = jnp.asarray(rng.standard_normal((L, A, T, K, H)) * 3, jnp.bfloat16)
+    vs = jnp.asarray(rng.standard_normal((L, A, T, K, H)) * 3, jnp.bfloat16)
+    cache = _random_paged(rng, L, A, NP, P, K, H, quantized, [0] * A)
+    before = _pools(cache)
+    off = None if pos_offset is None else jnp.int32(pos_offset * P)
+
+    args = (jnp.asarray(table), ks, vs, jnp.asarray(lens))
+    new = jax.jit(write_prompts_paged)(cache, *args, pos_offset=off)
+    old = jax.jit(oracle.write_prompts_paged)(cache, *args, pos_offset=off)
+    got, want = _pools(new), _pools(old)
+    reached = set()
+    for name in want:
+        for a in range(A):
+            for t in range(int(lens[a])):
+                page, o = table[a, first + t // P], t % P
+                reached.add(int(page))
+                np.testing.assert_array_equal(
+                    got[name][:, page, o], want[name][:, page, o], name
+                )
+    assert len(reached) == sum(-(-int(n) // P) for n in lens)
+    for name in want:
+        for page in set(range(NP - 1)) - reached:
+            np.testing.assert_array_equal(got[name][:, page], before[name][:, page])
+
+
+@pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
+def test_decode_stream_equals_old_write_for_64_steps(paged, monkeypatch):
+    """The tiny model's token streams, 8 chunks of 8, from the step program
+    as it is and from the same program with the old scatter put back."""
+    from pilottai_tpu.engine import decode as dec
+
+    cfg = get_model_config("llama-tiny")
+    params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+
+    def stream(step):
+        d_out, p_out, table = _admit_both(cfg, params, budgets=[70, 70, 0, 0])
+        c, d, s, _, _ = p_out if paged else d_out
+        toks = []
+        for _ in range(8):
+            t, v, c, d, s = step(
+                params, cfg, c, d, s, 8, use_pallas=False,
+                table=table if paged else None,
+            )
+            toks.append(np.asarray(t)[np.asarray(v)])
+        return np.concatenate(toks), np.asarray(c.lengths)
+
+    got, got_len = stream(decode_chunk)
+    monkeypatch.setattr(dec, "write_chunk_rows_paged", oracle.write_chunk_rows_paged)
+    monkeypatch.setattr(dec, "write_chunk_rows", oracle.write_chunk_rows)
+    old_program = jax.jit(
+        decode_chunk.__wrapped__,
+        static_argnames=("cfg", "n_steps", "use_pallas"),
+    )
+    want, want_len = stream(old_program)
+    assert got.size == 2 * 64
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(got_len, want_len)
 
 
 # --------------------------------------------------------------------- #
