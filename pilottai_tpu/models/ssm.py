@@ -18,7 +18,8 @@ inputs of the convolution (activation dtype) and ``h`` (float32,
 prompt admitted in segments carries them from segment to segment, and beyond
 a row's length it lets them stand still: ``dt`` is 0 there (``exp(0) = 1``,
 no input), and the convolution state kept is the last real positions'.
-``mamba_step`` leaves the rows that are not ``active`` exactly as they were.
+``mamba_step`` reads and writes the state of the ``active`` rows only and
+leaves the others exactly as they were.
 
 The prefill is a blocked scan in plain ``jax.numpy`` (chunks of
 ``ssm_chunk``: inside a chunk the recurrence is a masked matmul, between
@@ -35,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from pilottai_tpu.models.qmatmul import qmatmul
+from pilottai_tpu.ops.pallas.ssm_update import ssm_update, ssm_update_ok
 
 
 def _split(cfg: Any, zxbcdt: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -166,6 +168,29 @@ def mamba_prefill(
     return out, conv_state, h.reshape(A_, cfg.ssm_heads, P, N)
 
 
+def _live_rows(pool, decay, dx, Bm, Cm, active):
+    """The update of ``mamba_step`` in plain ``jax``, one live row at a
+    time: ``(pool', y [B, G, R, P])``."""
+    B, G, R, P = dx.shape
+    live = jnp.nonzero(active, size=B, fill_value=0)[0]
+
+    def row(k, carry):
+        pool, y = carry
+        b = live[k]
+        at = lambda a: jax.lax.dynamic_slice_in_dim(a, b, 1)
+        h = at(pool).reshape(1, G, R, P, -1)
+        h = h * at(decay)[..., None, None] + at(dx)[..., None] * at(Bm)[:, :, None, None, :]
+        pool = jax.lax.dynamic_update_slice_in_dim(pool, h.reshape((1,) + pool.shape[1:]), b, 0)
+        # the read-out reads the row back from the pool: reading the old
+        # row instead would have to keep the old pool beside the new one
+        h = at(pool).reshape(h.shape)
+        y_b = jnp.einsum("bgrpn,bgn->bgrp", h, at(Cm))
+        return pool, jax.lax.dynamic_update_slice_in_dim(y, y_b, b, 0)
+
+    return jax.lax.fori_loop(
+        0, jnp.sum(active, dtype=jnp.int32), row, (pool, jnp.zeros(dx.shape, jnp.float32)))
+
+
 @jax.named_scope("ssm")
 def mamba_step(
     cfg: Any,
@@ -177,9 +202,21 @@ def mamba_step(
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One token for every row; a row that is not ``active`` keeps both
     states to the bit (a slot between two segments of its prompt is such a
-    row)."""
+    row, and so is a free slot or one that finished earlier in the chunk).
+
+    The float32 state of the active rows alone is read and written, in
+    place on ``ssm0``: the rows are found on the device from ``active``, so
+    the program is the same whatever is live. The pool is 64 slots x 4.2 MB
+    a layer at Nemotron-3-Super's widths, and updating every row, live or
+    not, took a third of a decode step's device time with four live
+    (PERF.md §6). On a TPU, at the shapes it takes, the update is one
+    Pallas kernel over the live rows (``ops/pallas/ssm_update.py``: a row
+    read and written once); elsewhere a loop of ``dynamic_update_slice``
+    a live row. A row that is not active reads ``y = 0`` under the ``D xs``
+    term: finite, and discarded by the caller.
+    """
     B = u.shape[0]
-    G, R = cfg.ssm_groups, cfg.ssm_heads // cfg.ssm_groups
+    H, G, R = cfg.ssm_heads, cfg.ssm_groups, cfg.ssm_heads // cfg.ssm_groups
     P, N = cfg.ssm_head_dim, cfg.ssm_state
     z, xbc, dt = _split(cfg, qmatmul(u[:, 0], p["in_proj"]))
     with jax.named_scope("ssm_conv"):
@@ -191,14 +228,16 @@ def mamba_step(
         conv_state = jnp.where(active[:, None, None], window[:, 1:], conv0)
     xs, Bm, Cm = _heads(cfg, conv)                          # [B, G, R, P], [B, G, N]
     with jax.named_scope("ssm_scan"):
-        d = jnp.where(active[:, None], _dt(p, dt), 0.0).reshape(B, G, R)
+        d = _dt(p, dt).reshape(B, G, R)
         Aneg = -jnp.exp(p["A_log"].astype(jnp.float32)).reshape(G, R)
-        h = ssm0.reshape(B, G, R, P, N)
-        h = (
-            h * jnp.exp(d * Aneg)[..., None, None]
-            + (d[..., None] * xs)[..., None] * Bm[:, :, None, None, :]
-        )
-        y = jnp.einsum("bgrpn,bgn->bgrp", h, Cm)
+        decay = jnp.exp(d * Aneg)                           # [B, G, R]
+        dx = d[..., None] * xs                              # [B, G, R, P]
+        if jax.default_backend() == "tpu" and ssm_update_ok(H, P, N):
+            ssm, y = ssm_update(
+                ssm0, decay.reshape(B, H), dx.reshape(B, H, P), Bm, Cm, active, groups=G)
+            y = y.reshape(B, G, R, P)
+        else:
+            ssm, y = _live_rows(ssm0, decay, dx, Bm, Cm, active)
     y = y + p["D"].astype(jnp.float32).reshape(G, R)[..., None] * xs
     out = _gated_out(cfg, p, y.reshape(B, cfg.ssm_inner), z)
-    return out[:, None], conv_state, h.reshape(ssm0.shape)
+    return out[:, None], conv_state, ssm

@@ -125,9 +125,12 @@ class StatePool(NamedTuple):
     with it and has one owner.
 
     An admission OVERWRITES its slot's rows (a fresh prompt starts from
-    zeros inside the program, never from what the slot held), decode steps
-    update the rows of live slots in place, and a row that is not live
-    stands still. So a released slot needs no clearing.
+    zeros inside the program, never from what the slot held). A decode
+    step reads and writes the state-space rows of the live slots alone, one
+    row at a time in place on the pool the decode loop carries
+    (``models/ssm.py:mamba_step``); a row that is not live is neither read
+    nor written, and its conv rows stand still. So a released slot needs
+    no clearing.
 
     ``routed`` is no state of a slot: two running counts, summed on the
     device by the expert layers (token-expert pairs routed; those that

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from pilottai_tpu.engine import batcher as engine_batcher
 from pilottai_tpu.engine.batcher import ContinuousBatcher, GenRequest
 from pilottai_tpu.models import get_model_config, init_params
 from pilottai_tpu.models.common import param_logical_axes
@@ -122,6 +123,42 @@ def test_decode_between_the_segments_of_a_prompt_leaves_its_state_alone(paged, p
     paged.submit(r2)
     assert r2.future.result(timeout=600) == greedy(params, long)
     assert r1.future.result(timeout=600) == greedy(params, short, 40)
+
+
+def test_decode_with_live_slots_apart_and_one_ending_mid_chunk_is_the_full_forward_pass(
+        params, monkeypatch):
+    """Four slots, chunks of four steps: the second and fourth requests end
+    after one and two decode steps, inside the first chunk, and the first and
+    third decode on from slots with a free one between them. Every answer
+    is still the full forward pass's, so each live row moved its own state
+    and no other."""
+    seen = []       # per decode dispatch: slots live entering it, valid [n, B]
+    decode_chunk = engine_batcher.decode_chunk
+
+    def recorded(params_, cfg, cache, dstate, *a, **kw):
+        live = ~np.asarray(dstate.done)
+        out = decode_chunk(params_, cfg, cache, dstate, *a, **kw)
+        seen.append((live, np.asarray(out[1])))
+        return out
+
+    monkeypatch.setattr(engine_batcher, "decode_chunk", recorded)
+    b = batcher(params, chunk_size=4)
+    try:
+        ps = prompts(30, 12, 25, 9, seed=9)
+        budgets = (11, 2, 13, 3)
+        reqs = [GenRequest(prompt_ids=p, max_new_tokens=n, eos_id=-1) for p, n in zip(ps, budgets)]
+        b._submit_together(reqs)
+        got = [r.future.result(timeout=600) for r in reqs]
+    finally:
+        b.stop()
+    for p, n, tokens in zip(ps, budgets, got):
+        assert tokens == greedy(params, p, n)
+    # what the test is about did happen: a chunk in which a slot stopped
+    # after its first step while others went on, and a chunk whose live
+    # slots have a free one between them
+    assert any(v[0].sum() > v[1].sum() > 0 for _, v in seen)
+    assert any(np.flatnonzero(live).size > 1 and np.any(np.diff(np.flatnonzero(live)) > 1)
+               for live, _ in seen)
 
 
 def test_a_slot_reused_after_a_long_request_answers_as_a_fresh_one(params):

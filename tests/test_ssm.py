@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from pilottai_tpu.models import get_model_config, init_params, ssm
+from pilottai_tpu.ops.pallas.ssm_update import ssm_update
 
 CFG = get_model_config("nemotron-h-tiny").replace(dtype=jnp.float32)
 # float32 sums in another order: a few units in the last place of values of
@@ -111,3 +112,78 @@ def test_the_one_token_update_is_one_more_token_of_prefill(layer):
     np.testing.assert_array_equal(conv2[1], conv[1])
     np.testing.assert_array_equal(state2[1], state[1])
     np.testing.assert_allclose(state2[0], wstate[0], **TOL)
+
+
+def whole_pool_step(cfg, p, u, conv0, ssm0, active):
+    """The one-token update over every row of the pool, ``d`` zeroed where a
+    row is not active: the form ``mamba_step`` had before it moved the live
+    rows alone, kept here as the yardstick."""
+    B = u.shape[0]
+    G, R = cfg.ssm_groups, cfg.ssm_heads // cfg.ssm_groups
+    P, N = cfg.ssm_head_dim, cfg.ssm_state
+    z, xbc, dt = ssm._split(cfg, u[:, 0] @ p["in_proj"])
+    window = jnp.concatenate([conv0, xbc[:, None].astype(conv0.dtype)], axis=1)
+    acc = jnp.einsum("bkc,kc->bc", window, p["conv_w"]) + p["conv_b"]
+    conv_state = jnp.where(active[:, None, None], window[:, 1:], conv0)
+    xs, Bm, Cm = ssm._heads(cfg, jax.nn.silu(acc))
+    d = jnp.where(active[:, None], ssm._dt(p, dt), 0.0).reshape(B, G, R)
+    A = -jnp.exp(p["A_log"]).reshape(G, R)
+    h = ssm0.reshape(B, G, R, P, N)
+    h = h * jnp.exp(d * A)[..., None, None] + (d[..., None] * xs)[..., None] * Bm[:, :, None, None, :]
+    y = jnp.einsum("bgrpn,bgn->bgrp", h, Cm) + p["D"].reshape(G, R)[..., None] * xs
+    out = ssm._gated_out(cfg, p, y.reshape(B, cfg.ssm_inner), z)
+    return out[:, None], conv_state, h.reshape(ssm0.shape)
+
+
+LIVE = dict(argvalues=[(), (3,), (0, 5, 7), tuple(range(8))],
+            ids=["no_row", "one_row", "rows_0_5_7", "every_row"])
+
+
+@pytest.mark.parametrize("live", **LIVE)
+def test_the_update_moves_the_live_rows_as_the_whole_pool_formula_does(layer, live):
+    """Eight slots whose pool holds state from a prefill: the live rows come
+    out as the whole-pool formula computes them, every other row of both
+    states stays as it was to the bit, and every output is finite."""
+    B = 8
+    k = jax.random.split(jax.random.PRNGKey(11), 2)
+    u = jax.random.normal(k[0], (B, 12, CFG.hidden_size))
+    _, conv, state = ssm.mamba_prefill(CFG, layer, u, jnp.arange(5, 5 + B))
+    nxt = jax.random.normal(k[1], (B, 1, CFG.hidden_size))
+    active = jnp.zeros((B,), bool).at[jnp.asarray(live, jnp.int32)].set(True)
+    out, conv1, state1 = jax.jit(ssm.mamba_step, static_argnums=0)(
+        CFG, layer, nxt, conv, state, active)
+    want, wconv, wstate = whole_pool_step(CFG, layer, nxt, conv, state, active)
+    on = np.asarray(active)
+    np.testing.assert_array_equal(np.asarray(conv1)[~on], np.asarray(conv)[~on])
+    np.testing.assert_array_equal(np.asarray(state1)[~on], np.asarray(state)[~on])
+    np.testing.assert_allclose(np.asarray(conv1)[on], np.asarray(wconv)[on], **TOL)
+    np.testing.assert_allclose(np.asarray(state1)[on], np.asarray(wstate)[on], **TOL)
+    np.testing.assert_allclose(np.asarray(out)[on], np.asarray(want)[on], **TOL)
+    assert np.isfinite(np.asarray(out)).all()
+    if live:       # the live rows did move
+        assert not np.array_equal(np.asarray(state1)[on], np.asarray(state)[on])
+
+
+@pytest.mark.parametrize("live", **LIVE)
+def test_the_kernel_moves_the_live_rows_as_the_whole_pool_formula_does(live):
+    """``ops/pallas/ssm_update.py`` in interpret mode, at a shape it takes
+    (a state of 128, two heads of 64 to a tile), on eight slots: the live
+    rows as the whole-pool formula computes them, every other row of the
+    pool as it was to the bit and of ``y`` zero."""
+    B, G, R, P, N = 8, 2, 2, 64, 128
+    k = jax.random.split(jax.random.PRNGKey(12), 5)
+    pool = jax.random.normal(k[0], (B, G * R, P, N))
+    decay = jax.random.uniform(k[1], (B, G * R))
+    dx = jax.random.normal(k[2], (B, G * R, P)) * 0.1
+    Bm, Cm = jax.random.normal(k[3], (2, B, G, N))
+    active = jnp.zeros((B,), bool).at[jnp.asarray(live, jnp.int32)].set(True)
+    new, y = jax.jit(lambda *a: ssm_update(*a, groups=G, interpret=True))(
+        pool, decay, dx, Bm, Cm, active)
+    h = (pool.reshape(B, G, R, P, N) * decay.reshape(B, G, R)[..., None, None]
+         + dx.reshape(B, G, R, P)[..., None] * Bm[:, :, None, None, :])
+    want_y = jnp.einsum("bgrpn,bgn->bgrp", h, Cm).reshape(B, G * R, P)
+    on = np.asarray(active)
+    np.testing.assert_array_equal(np.asarray(new)[~on], np.asarray(pool)[~on])
+    np.testing.assert_array_equal(np.asarray(y)[~on], 0.0)
+    np.testing.assert_allclose(np.asarray(new)[on], np.asarray(h.reshape(pool.shape))[on], **TOL)
+    np.testing.assert_allclose(np.asarray(y)[on], np.asarray(want_y)[on], **TOL)

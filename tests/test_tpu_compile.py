@@ -212,3 +212,29 @@ def test_native_qmatmul_compiles_at_8b_mlp_shape(spec, bits, monkeypatch):
     assert "s32[" in text, "no int32 accumulation in the native lowering"
     for dense in (f"f32[{HIDDEN},{FFN}]", f"bf16[{HIDDEN},{FFN}]"):
         assert dense not in text, f"dense weight buffer {dense} materialised"
+
+
+# ---------------------------------------------------------------------- #
+# Mamba-2 decode update of the live slots (ops/pallas/ssm_update.py)
+# ---------------------------------------------------------------------- #
+
+def test_ssm_update_compiles_at_nemotron_shapes_in_place(spec):
+    """The kernel at Nemotron-3-Super's widths (64 slots, 128 heads of 64 in
+    8 groups, a state of 128): the chip's compiler takes it, the pool is
+    the output's buffer, and no copy of the pool is made around the call."""
+    from pilottai_tpu.ops.pallas.ssm_update import ssm_update, ssm_update_ok
+
+    B, H, P, N, G = 64, 128, 64, 128, 8
+    assert ssm_update_ok(H, P, N)
+    compiled = jax.jit(
+        lambda *a: ssm_update(*a, groups=G), donate_argnums=0
+    ).lower(
+        spec((B, H, P, N), jnp.float32), spec((B, H), jnp.float32),
+        spec((B, H, P), jnp.float32), spec((B, G, N), jnp.float32),
+        spec((B, G, N), jnp.float32), spec((B,), jnp.bool_),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert compiled.memory_analysis().alias_size_in_bytes >= B * H * P * N * 4
+    pool = f"f32[{B},{H},{P},{N}]"
+    assert not [line for line in text.splitlines() if pool in line and " copy(" in line]
